@@ -104,7 +104,7 @@ def fig_lane_kernel(v=800, e=3200, m=3, k=2, lane_counts=(1, 4, 8)):
     from repro.engine import ExecutionPolicy, QueryEngine
     from repro.graph.generators import lod_like_graph
     from repro.graph.index import InvertedIndex, mid_df_tokens
-    from repro.kernels.lane_superstep import interpret_default
+    from repro.kernels import interpret_mode
 
     g, tokens = lod_like_graph(v, e, seed=0, vocab=60, tau=1001)
     index = InvertedIndex.from_token_matrix(tokens)
@@ -156,7 +156,7 @@ def fig_lane_kernel(v=800, e=3200, m=3, k=2, lane_counts=(1, 4, 8)):
         })
     return {
         "graph": {"v": v, "e": e, "m": m, "k": k},
-        "interpret": interpret_default(),
+        "interpret": interpret_mode(),
         "jaxpr_eqns": jaxpr_eqns,
         "pallas_calls_per_superstep": pallas_calls,
         "rows": rows,

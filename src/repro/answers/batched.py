@@ -45,6 +45,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import INF
+from repro.core import semiring
 from repro.core.reconstruct import _TOL, AnswerTree, backtrace, collect_answers
 from repro.graph.structure import Graph
 
@@ -307,12 +308,9 @@ class BatchedBacktracer:
 
         def kernel(S_lanes, kw_lanes):
             # Candidate selection: value-ascending with ties at lower cell
-            # index first (top_k of the negated values), matching the
-            # host's stable argsort exactly.
-            flat = S_lanes[:, :, full, :].reshape(L, -1)
-            neg, idx = jax.lax.top_k(-flat, C)
-            vals = -neg
-            roots = (idx // K).astype(jnp.int32)
+            # index first, matching the host's stable argsort exactly.
+            vals, idx = semiring.smallest_k_2d(S_lanes[:, :, full, :], C)
+            roots = idx // K
             valid = vals < inf
             per_cand = jax.vmap(one, in_axes=(None, None, 0, 0, 0))
             per_lane = jax.vmap(per_cand, in_axes=(0, 0, 0, 0, 0))

@@ -154,11 +154,9 @@ def relax(graph: DeviceGraph, S: jax.Array, changed: jax.Array,
     cand = S[graph.src] + graph.w[:, None, None]
     cand = jnp.where(send[:, None, None], cand, INF)
     cand = semiring.bump_to_inf(cand)
-    e_pad, n, k = cand.shape
     # Candidate axis = (edge, slot); segment by destination.
-    vals = cand.transpose(0, 2, 1).reshape(e_pad * k, n)
-    seg = jnp.repeat(graph.dst, k)
-    return semiring.segment_topk_min(vals, seg, graph.v_pad, cfg.k)  # [V, 2^m, K]
+    return semiring.segment_topk_min(cand, graph.dst, graph.v_pad, cfg.k,
+                                     pooled=True)  # [V, 2^m, K]
 
 
 def combine(S: jax.Array, cfg: DKSConfig) -> jax.Array:
@@ -206,10 +204,8 @@ def aggregate(graph: DeviceGraph, state: DKSState, cfg: DKSConfig) -> DKSState:
     masked = jnp.where(changed[:, None], S[:, :, 0], INF)  # [V, 2^m]
     s_front = jnp.min(masked, axis=0)
     g = jnp.minimum(state.g, jnp.min(S[:, :, 0], axis=0))
-    full_vals = S[:, cfg.full, :].reshape(-1)               # [V*K]
-    neg_top, idx = jax.lax.top_k(-full_vals, cfg.k)
-    topk_w = -neg_top
-    topk_root = (idx // cfg.k).astype(jnp.int32)
+    topk_w, idx = semiring.smallest_k_2d(S[:, cfg.full, :], cfg.k)
+    topk_root = idx // cfg.k
     topk_root = jnp.where(topk_w >= INF, -1, topk_root)
     return dataclasses.replace(
         state, s_front=s_front, g=g, topk_w=topk_w, topk_root=topk_root
@@ -377,10 +373,8 @@ def run_dks_instrumented(
         return semiring.bump_to_inf(cand)
 
     def _receive_one(S, cand):
-        e_pad, n, k = cand.shape
-        vals = cand.transpose(0, 2, 1).reshape(e_pad * k, n)
-        seg = jnp.repeat(graph.dst, k)
-        r = semiring.segment_topk_min(vals, seg, graph.v_pad, cfg.k)
+        r = semiring.segment_topk_min(cand, graph.dst, graph.v_pad, cfg.k,
+                                      pooled=True)
         return semiring.topk_merge(S, r)
 
     @jax.jit

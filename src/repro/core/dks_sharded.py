@@ -28,9 +28,9 @@ axis replicated), so it needs no collectives at all.
 
 The mesh is *explicit*: :func:`pack_frontier_graph` records it on the
 :class:`FrontierGraph` (a static pytree field), and every executor reads it
-from there — no ambient ``get_abstract_mesh()`` state.  All shard_map/mesh
-API calls go through :mod:`repro.shardmap`, so this path runs on both jax
-0.4.x and >= 0.7.
+from there — no ambient ``get_abstract_mesh()`` state — and places the
+packed shards on it, one per device.  All shard_map/mesh API calls go
+through :mod:`repro.shardmap`.
 """
 
 from __future__ import annotations
@@ -43,7 +43,7 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro import INF, shardmap
 from repro.core import semiring
@@ -99,8 +99,10 @@ def pack_frontier_graph(g: Graph, n_shards: int | None = None,
     """Host-side: symmetrized edges grouped by dst owner, padded rows.
 
     ``mesh``: the mesh the shards will execute on; recorded on the result so
-    the executors need no ambient mesh state.  ``n_shards`` defaults to the
-    mesh's device count when a mesh is given.
+    the executors need no ambient mesh state, and the packed arrays are
+    placed on it (shard ``s``'s edges and node slice on the mesh's ``s``-th
+    device), so no dispatch re-lays them out.  ``n_shards`` defaults to
+    the mesh's device count when a mesh is given.
     """
     if n_shards is None:
         if mesh is None:
@@ -131,10 +133,17 @@ def pack_frontier_graph(g: Graph, n_shards: int | None = None,
     out_degree[: g.n_nodes] = deg
     node_valid = np.zeros(v_pad, bool)
     node_valid[: g.n_nodes] = True
+    put = jnp.asarray
+    if mesh is not None:
+        axes = _mesh_axes(mesh)
+
+        def put(x):
+            spec = P(axes, *([None] * (x.ndim - 1)))
+            return jax.device_put(x, NamedSharding(mesh, spec))
     return FrontierGraph(
-        edge_src=jnp.asarray(edge_src), edge_dst_l=jnp.asarray(edge_dst_l),
-        edge_w=jnp.asarray(edge_w), out_degree=jnp.asarray(out_degree),
-        node_valid=jnp.asarray(node_valid),
+        edge_src=put(edge_src), edge_dst_l=put(edge_dst_l),
+        edge_w=put(edge_w), out_degree=put(out_degree),
+        node_valid=put(node_valid),
         n_nodes=g.n_nodes, n_edges=len(src), n_shards=n_shards, mesh=mesh)
 
 
@@ -170,7 +179,7 @@ def relax_frontier_lanes(graph: FrontierGraph, S: jax.Array,
     n_shards = graph.n_shards
     n_loc = graph.n_loc
     f_cap = min(n_loc, max(1, int(n_loc * cfg.frontier_frac)))
-    n_sets, k = S.shape[2], S.shape[3]
+    k = S.shape[3]
     f_tot = n_shards * f_cap
 
     def block(S_loc, changed_loc, src_g, dst_l, w, shard_arange):
@@ -208,10 +217,8 @@ def relax_frontier_lanes(graph: FrontierGraph, S: jax.Array,
             cand = st[pos] + w[:, None, None]
             cand = jnp.where(hit[:, None, None], cand, INF)
             cand = semiring.bump_to_inf(cand)
-            e_cap = cand.shape[0]
-            vals = cand.transpose(0, 2, 1).reshape(e_cap * k, n_sets)
-            seg = jnp.repeat(dst_l, k)
-            return semiring.segment_topk_min(vals, seg, n_loc, k)
+            return semiring.segment_topk_min(cand, dst_l, n_loc, k,
+                                             pooled=True)
 
         r_loc = jax.vmap(relax_lane)(all_gids, all_tab)  # [L,n_loc,S,K]
         ov = jax.lax.pmax(overflow.astype(jnp.int32), axes)

@@ -64,11 +64,17 @@ def segment_topk_min(
     segment_ids: jax.Array,
     num_segments: int,
     k: int,
+    pooled: bool = False,
 ) -> jax.Array:
     """Exact per-segment top-K smallest *distinct* values.
 
     ``values``: (N, ...F) candidate values; ``segment_ids``: (N,) int32.
     Returns (num_segments, ...F, k), sorted-unique-INF-padded.
+    ``pooled``: the last axis of ``values`` holds further candidates of
+    the same (row, feature) cell (``values``: (N, ...F, P)), reduced
+    along with the rows — the same result as folding it into the row
+    axis, without the reshape, which on TPU costs minutes of compile at
+    graph widths.
 
     Implementation: K rounds of (segment-min -> winner masking).  Each round
     extracts one distinct minimum per (segment, feature) cell; every candidate
@@ -82,12 +88,43 @@ def segment_topk_min(
             vals, segment_ids, num_segments=num_segments,
             indices_are_sorted=False, unique_indices=False,
         )
+        if pooled:
+            cur = jnp.min(cur, axis=-1)
         cur = jnp.minimum(cur, INF)
         outs.append(cur)
         # Mask every candidate equal to its segment's extracted minimum.
-        vals = jnp.where(vals <= cur[segment_ids], INF, vals)
+        won = cur[segment_ids]
+        if pooled:
+            won = won[..., None]
+        vals = jnp.where(vals <= won, INF, vals)
     out = jnp.stack(outs, axis=-1)
     return out
+
+
+def smallest_k_2d(x: jax.Array, k: int) -> tuple[jax.Array, jax.Array]:
+    """The ``k`` smallest entries of ``x[..., N, M]`` over its last two
+    axes, ordered by (value, row-major flat index): exactly what
+    ``lax.top_k`` of the negated ``x.reshape(..., N * M)`` selects, ties
+    at the lower index first.  Returns ``(values[..., k], flat
+    indices[..., k])``.
+
+    ``k`` rounds of min-reductions instead of that reshape: on TPU,
+    flattening a narrow minor axis (``M`` = K slots) ahead of ``top_k``
+    costs minutes of compile at graph widths.
+    """
+    n, m = x.shape[-2:]
+    flat = jnp.arange(n * m, dtype=jnp.int32).reshape(n, m)
+    taken = jnp.zeros(x.shape, bool)
+    vals, idxs = [], []
+    for _ in range(k):
+        free = jnp.where(taken, jnp.inf, x)
+        cur = jnp.min(free, axis=(-2, -1))
+        hit = ~taken & (x == cur[..., None, None])
+        i = jnp.min(jnp.where(hit, flat, n * m), axis=(-2, -1))
+        taken = taken | (flat == i[..., None, None])
+        vals.append(cur)
+        idxs.append(i)
+    return jnp.stack(vals, axis=-1), jnp.stack(idxs, axis=-1)
 
 
 def bump_to_inf(x: jax.Array, thresh: float = INF * 0.5) -> jax.Array:

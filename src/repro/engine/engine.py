@@ -129,9 +129,11 @@ class QueryEngine:
         self.batched_extraction = True
         # backend="pallas": the fused lane-superstep kernel's padded-CSR
         # layout, built once per graph by ``build`` (None on jnp/sharded
-        # engines).  Executables close over it and thread it into
-        # ``lane_superstep`` — layout cost is paid at build, not per query.
+        # engines).  Every executable takes it as an argument and threads
+        # it into ``lane_superstep`` — layout cost is paid at build, not
+        # per query.  ``lane_csr_build_s``: host seconds that build took.
         self.lane_csr: Any = None
+        self.lane_csr_build_s = 0.0
 
     # ------------------------------------------------------------------
     # Construction
@@ -217,7 +219,9 @@ class QueryEngine:
             # flowed into the kernel's weight table.
             from repro.kernels.lane_superstep import (
                 lane_csr_from_device_graph)
+            t0 = time.perf_counter()
             engine.lane_csr = lane_csr_from_device_graph(device_graph)
+            engine.lane_csr_build_s = time.perf_counter() - t0
         return engine
 
     # ------------------------------------------------------------------
@@ -676,7 +680,7 @@ class QueryEngine:
                 self.graph, max(cfg.k, extract_pool or 0))
         t0 = time.perf_counter()
         deadline_t = t0 + max(deadline_s, 0.0)
-        state = self._execute(init_fn, self.device_graph, jnp.asarray(masks))
+        state = self._execute(init_fn, jnp.asarray(masks))
         own_t: list[float | None] = [None] * len(queries)
         driver_steps = 0
         while True:
@@ -695,7 +699,7 @@ class QueryEngine:
                                        masks[i][:, : self.n_nodes])
             if done[:n_real].all() or now >= deadline_t:
                 break
-            state = self._execute(step_fn, self.device_graph, state)
+            state = self._execute(step_fn, state)
             driver_steps += 1
         dt = time.perf_counter() - t0
         out: list[tuple[QueryResult, dict[str, Any]] | None] = []
@@ -783,8 +787,7 @@ class QueryEngine:
         a host loop over the 1-lane stepwise driver.  Yields un-batched
         lane views, so result construction stays lane-free."""
         init_fn, step_fn = self._executable(cfg, "stepwise")
-        states = self._execute(init_fn, self.device_graph,
-                               jnp.asarray(masks[None]))
+        states = self._execute(init_fn, jnp.asarray(masks[None]))
         opt_lb = 0.0
         sound_lb = 0.0
         while True:
@@ -817,7 +820,7 @@ class QueryEngine:
             )
             if done or int(state.step) >= cfg.max_supersteps:
                 return
-            states = self._execute(step_fn, self.device_graph, states)
+            states = self._execute(step_fn, states)
 
     def query_instrumented(
         self,
@@ -873,12 +876,14 @@ class QueryEngine:
         """
         return shardmap.mesh_scope(self.mesh)
 
-    def _execute(self, fn, *args):
-        """Run a compiled executor under the engine's mesh (if any) and
-        block until the result is materialized."""
+    def _execute(self, fn, x):
+        """Run a compiled executor on the engine's device graph and lane
+        layout under the engine's mesh (if any), and block until the
+        result is materialized.  ``x``: the masks or the state."""
         self._execute_count += 1
         with self._mesh_context():
-            return jax.block_until_ready(fn(*args))
+            return jax.block_until_ready(
+                fn(self.device_graph, self.lane_csr, x))
 
     def _run_fused(self, cfg: DKSConfig, masks: np.ndarray):
         """One fused-driver dispatch over lane-batched masks.  Returns
@@ -889,11 +894,8 @@ class QueryEngine:
         only reads the state)."""
         fn = self._executable(cfg, "fused")
         if not self.policy.telemetry:
-            states = self._execute(fn, self.device_graph,
-                                   jnp.asarray(masks))
-            return states, None
-        states, buf, steps = self._execute(fn, self.device_graph,
-                                           jnp.asarray(masks))
+            return self._execute(fn, jnp.asarray(masks)), None
+        states, buf, steps = self._execute(fn, jnp.asarray(masks))
         telemetry = SuperstepTelemetry.from_buffer(np.asarray(buf),
                                                    int(steps))
         return states, telemetry
@@ -946,13 +948,11 @@ class QueryEngine:
         if fn is not None:
             return fn
 
-        # The fused pallas layout (None on jnp/sharded engines) rides the
-        # executor closures as a trace-time constant — same graph, same
-        # layout, for the engine's whole lifetime.
-        csr = self.lane_csr
-
+        # Every executor takes the graph and the fused pallas layout
+        # (None on jnp/sharded engines) as arguments: closed over, they
+        # would be embedded as constants in each compiled program.
         if kind == "fused":
-            def _run(graph, masks):
+            def _run(graph, csr, masks):
                 self._trace_counts[key] = self._trace_counts.get(key, 0) + 1
                 state = lane_init(graph, masks, cfg)
                 return jax.lax.while_loop(
@@ -964,17 +964,17 @@ class QueryEngine:
         elif kind == "fused-telemetry":
             # Same loop, same kernel, plus the bounded counter-buffer
             # carry (repro.core.driver.run_lanes_telemetry).
-            def _run_tel(graph, masks):
+            def _run_tel(graph, csr, masks):
                 self._trace_counts[key] = self._trace_counts.get(key, 0) + 1
                 return run_lanes_telemetry(graph, masks, cfg, csr=csr)
 
             fn = jax.jit(_run_tel)
         elif kind == "stepwise":
-            def _init(graph, masks):
+            def _init(graph, csr, masks):
                 self._trace_counts[key] = self._trace_counts.get(key, 0) + 1
                 return lane_init(graph, masks, cfg)
 
-            def _step(graph, st):
+            def _step(graph, csr, st):
                 self._trace_counts[key] = self._trace_counts.get(key, 0) + 1
                 return lane_superstep(graph, st, cfg, csr=csr)
 

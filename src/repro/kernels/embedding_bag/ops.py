@@ -2,9 +2,9 @@
 
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_mode
 from repro.kernels.embedding_bag.kernel import embedding_bag_kernel
 
 
@@ -12,7 +12,7 @@ def embedding_bag(table, ids, weights=None, mode: str = "sum",
                   block_b: int = 8, interpret: bool | None = None):
     """table [V, D]; ids [B, nnz] (-1 pad) -> [B, D]."""
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_mode()
     b, nnz = ids.shape
     pad = (-b) % block_b
     if weights is None:

@@ -2,9 +2,9 @@
 
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_mode
 from repro.kernels.flash_attention.kernel import flash_attention_bhsd
 
 
@@ -12,7 +12,7 @@ def flash_attention(q, k, v, *, causal: bool = True, q_offset: int = 0,
                     block: int = 128, interpret: bool | None = None):
     """q [B, Sq, Hq, Dh]; k/v [B, Skv, Hkv, Dh] -> [B, Sq, Hq, Dh]."""
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_mode()
     b, sq, hq, dh = q.shape
     _, skv, hkv, _ = k.shape
     block_q = min(block, max(8, sq))

@@ -1,6 +1,5 @@
 from repro.kernels.lane_superstep.ops import (  # noqa: F401
     LaneCSR,
     fused_lane_superstep,
-    interpret_default,
     lane_csr_from_device_graph,
 )

@@ -24,7 +24,11 @@ Layout (hardware adaptation, same choice as ``subset_combine``): virtual
 rows ride the minor 128-wide lane axis — ``cand[L, 2^m, dmax*K, Vv]``,
 ``S0/out [L, 2^m, K, Vv]`` — so every min/add/select is a full-width
 vector op.  VMEM per block: ``2^m * dmax * K * BV * 4B`` for the
-candidate tile (m=4, dmax=16, K=2, BV=128 -> 256 KiB).
+candidate tile (m=4, dmax=16, K=2, BV=128 -> 256 KiB), held twice
+(double-buffered) next to the reduce's working set.  On a v5e's 16 MiB
+of scoped VMEM, Mosaic takes a 4 MiB tile and refuses an 8 MiB one
+(m=3 K=2 at BV=8192 asks for 18.1 MB), so :func:`fused_lane_step`
+refuses tiles past :data:`MAX_CAND_TILE_BYTES` up front.
 
 Bit-identity to the jnp path holds because every stage reduces the same
 candidate multiset with the same distinct-top-K semantics: the combine
@@ -40,9 +44,15 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro import INF
 from repro.core.spa import split_pairs
+
+# Largest candidate tile (one block's f32[2^m, dmax*K, BV]) the kernel
+# takes: twice this, plus the reduce's temporaries, fits v5e's 16 MiB of
+# scoped VMEM.
+MAX_CAND_TILE_BYTES = 4 << 20
 
 
 def _topk_distinct(cand: jnp.ndarray, k: int, axis: int) -> jnp.ndarray:
@@ -63,11 +73,13 @@ def _merge2(a: jnp.ndarray, b: jnp.ndarray, k: int) -> jnp.ndarray:
 
 
 def _lane_step_kernel(seg_ref, done_ref, cand_ref, s0_ref, out_ref,
-                      *, m: int, k: int, bv: int):
+                      *, m: int, k: int, span: int):
     """One (lane, row-block) grid step.
 
     seg_ref:  i32[1, BV]   node id per virtual row (-1 on pad rows)
-    done_ref: i32[1, 1]    this lane's freeze flag
+    done_ref: i32[L]       every lane's freeze flag, whole in SMEM (a
+                           (1, 1) VMEM block of i32[L, 1] breaks the
+                           TPU's (8, 128) tiling rule)
     cand_ref: f32[1, 2^m, dmax*K, BV]  min-plus candidates
     s0_ref:   f32[1, 2^m, K, BV]       pre-relax table, gathered per row
     out_ref:  f32[1, 2^m, K, BV]       post-combine table (valid at each
@@ -75,7 +87,8 @@ def _lane_step_kernel(seg_ref, done_ref, cand_ref, s0_ref, out_ref,
     """
     cand = cand_ref[0]                              # [F, C, BV]
     s0 = s0_ref[0]                                  # [F, K, BV]
-    seg = seg_ref[0]                                # [BV]
+    seg = seg_ref[...]                              # [1, BV] (2-D: Mosaic
+    # cannot shift a 1-D vector by a whole 128-lane tile)
 
     # 1) per-row relax reduce: top-K distinct over the candidate axis.
     r = _topk_distinct(cand, k, axis=1)             # [F, K, BV]
@@ -84,56 +97,74 @@ def _lane_step_kernel(seg_ref, done_ref, cand_ref, s0_ref, out_ref,
     #    idempotent, so an inclusive Hillis–Steele scan leaves the full
     #    per-node merge at each segment's LAST row (the tail row the
     #    host gathers).  Pad rows (seg == -1) never join a segment.
+    #    Shifts below ``span`` (the most rows any node holds) reach every
+    #    segment's first row.
     shift = 1
-    while shift < bv:
+    while shift < span:
         prev = jnp.concatenate(
             [jnp.full(r.shape[:-1] + (shift,), INF, r.dtype),
              r[..., :-shift]], axis=-1)
         pseg = jnp.concatenate(
-            [jnp.full((shift,), -2, seg.dtype), seg[:-shift]], axis=0)
-        same = (seg == pseg) & (seg >= 0)           # [BV]
-        r = jnp.where(same[None, None, :], _merge2(r, prev, k), r)
+            [jnp.full((1, shift), -2, seg.dtype), seg[:, :-shift]], axis=1)
+        same = (seg == pseg) & (seg >= 0)           # [1, BV]
+        r = jnp.where(same[None], _merge2(r, prev, k), r)
         shift *= 2
 
     # 3) receive: merge what arrived with the node's previous table.
     s = _merge2(r, s0, k)                           # [F, K, BV]
 
     # 4) subset-combine sweep (popcount order -> closure in one pass).
+    #    The table is held as a list of [K, BV] rows and stacked once:
+    #    Mosaic has no scatter, so ``s.at[t].set`` cannot lower.
+    rows = list(s)
     for t, a, b in split_pairs(m):
-        pair = s[a][:, None, :] + s[b][None, :, :]  # [K, K, BV]
+        pair = rows[a][:, None, :] + rows[b][None, :, :]  # [K, K, BV]
         pair = jnp.minimum(pair, INF)
         cand_t = jnp.concatenate(
-            [s[t], pair.reshape(k * k, -1)], axis=0)  # [K+K^2, BV]
-        s = s.at[t].set(_topk_distinct(cand_t, k, axis=0))
+            [rows[t], pair.reshape(k * k, -1)], axis=0)  # [K+K^2, BV]
+        rows[t] = _topk_distinct(cand_t, k, axis=0)
+    s = jnp.stack(rows)
 
     # 5) per-lane freeze: a finished lane keeps its pre-step table.
-    frozen = done_ref[0, 0] != 0
+    frozen = done_ref[pl.program_id(0)] != 0
     out_ref[0] = jnp.where(frozen, s0, s)
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("m", "block_v", "interpret"))
+                   static_argnames=("m", "block_v", "span", "interpret"))
 def fused_lane_step(
     cand_t: jax.Array,   # f32[L, 2^m, dmax*K, Vv]
     s0_t: jax.Array,     # f32[L, 2^m, K, Vv]
     seg: jax.Array,      # i32[1, Vv]
-    done: jax.Array,     # i32[L, 1]
+    done: jax.Array,     # i32[L]
     m: int,
     block_v: int = 128,
+    span: int | None = None,
     interpret: bool = False,
 ) -> jax.Array:
     """The fused superstep body as ONE pallas launch over
-    ``grid = (lanes, Vv / block_v)``.  Returns f32[L, 2^m, K, Vv]."""
+    ``grid = (lanes, Vv / block_v)``.  ``span``: the most virtual rows
+    one node holds (default ``block_v``), which bounds the hub-merge
+    scan.  Returns f32[L, 2^m, K, Vv]."""
     lanes, n_sets, c, vv = cand_t.shape
     k = s0_t.shape[2]
     assert n_sets == 1 << m and vv % block_v == 0
+    span = block_v if span is None else span
+    assert 1 <= span <= block_v
+    tile = n_sets * c * block_v * cand_t.dtype.itemsize
+    if tile > MAX_CAND_TILE_BYTES:
+        raise ValueError(
+            f"fused lane step: a {block_v}-row block at m={m}, "
+            f"{c} candidates per row needs a {tile:,} B candidate tile in "
+            f"VMEM, over the {MAX_CAND_TILE_BYTES:,} B the kernel takes; "
+            f"the graph's largest hub sets the block (use backend='jnp')")
     grid = (lanes, vv // block_v)
     return pl.pallas_call(
-        functools.partial(_lane_step_kernel, m=m, k=k, bv=block_v),
+        functools.partial(_lane_step_kernel, m=m, k=k, span=span),
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_v), lambda l, i: (0, i)),
-            pl.BlockSpec((1, 1), lambda l, i: (l, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, n_sets, c, block_v), lambda l, i: (l, 0, 0, i)),
             pl.BlockSpec((1, n_sets, k, block_v), lambda l, i: (l, 0, 0, i)),
         ],

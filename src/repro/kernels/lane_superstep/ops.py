@@ -34,7 +34,14 @@ import numpy as np
 from repro import INF
 from repro.core import semiring
 from repro.core.dks import DKSConfig, DKSState, finish_superstep
+from repro.kernels import interpret_mode
 from repro.kernels.lane_superstep.kernel import fused_lane_step
+
+# Widest row block a layout takes: at the default dmax=16, its m=3, K=2
+# candidate tile (2^3 * 16*2 * 4096 * 4 B) is the kernel's whole
+# MAX_CAND_TILE_BYTES.  The kernel refuses wider query shapes on a
+# layout this wide at trace time.
+MAX_BLOCK_V = 4096
 
 
 @jax.tree_util.register_dataclass
@@ -54,6 +61,8 @@ class LaneCSR:
       tail_row: i32[v_pad] LAST virtual row of each node — where the
         kernel's segmented scan leaves the complete merge.
       dmax / block_v / n_rows: static layout parameters.
+      span: the most virtual rows any one node holds (bounds the
+        kernel's hub-merge scan).
     """
 
     src_pad: jax.Array
@@ -64,15 +73,19 @@ class LaneCSR:
     dmax: int = dataclasses.field(metadata=dict(static=True))
     block_v: int = dataclasses.field(metadata=dict(static=True))
     n_rows: int = dataclasses.field(metadata=dict(static=True))
+    span: int = dataclasses.field(metadata=dict(static=True))
 
 
 def lane_csr_from_device_graph(graph, dmax: int = 16,
                                block_v: int = 128) -> LaneCSR:
     """Build the kernel layout from a dense :class:`DeviceGraph`.
 
-    Host-side numpy, paid once per ``QueryEngine.build``.  ``dmax``
-    auto-bumps so every node fits in at most ``block_v`` virtual rows
-    (the block-alignment invariant is unconditional).
+    Host-side numpy, paid once per ``QueryEngine.build``.  ``block_v``
+    doubles until every node fits in at most ``block_v`` virtual rows
+    (the block-alignment invariant is unconditional).  Growing the block
+    rather than ``dmax`` keeps one hub from widening every row of the
+    candidate tensor.  A node whose in-edges exceed ``dmax *
+    MAX_BLOCK_V`` cannot fit one block in VMEM: ValueError.
     """
     valid = np.asarray(graph.valid)
     src = np.asarray(graph.src)[valid].astype(np.int64)
@@ -82,8 +95,14 @@ def lane_csr_from_device_graph(graph, dmax: int = 16,
 
     deg = np.bincount(dst, minlength=n).astype(np.int64)
     max_deg = int(deg.max()) if deg.size else 0
-    if max_deg > dmax * block_v:
-        dmax = int(np.ceil(max_deg / block_v))
+    if max_deg > dmax * MAX_BLOCK_V:
+        raise ValueError(
+            f"node {int(deg.argmax())} has {max_deg:,} in-edges; the fused "
+            f"kernel's layout holds at most {dmax * MAX_BLOCK_V:,} per node "
+            f"(one {MAX_BLOCK_V}-row block of {dmax} slots in VMEM); use "
+            f"backend='jnp'")
+    while max_deg > dmax * block_v:
+        block_v *= 2
     rows = np.maximum(1, -(-deg // dmax))           # ceil, >= 1 row/node
 
     # Block-aligned row starts: advance to the next block boundary when a
@@ -119,14 +138,21 @@ def lane_csr_from_device_graph(graph, dmax: int = 16,
         gather_of=jnp.asarray(np.maximum(seg, 0).astype(np.int32)),
         seg=jnp.asarray(seg), tail_row=jnp.asarray(tail_row),
         dmax=int(dmax), block_v=int(block_v), n_rows=int(n_rows),
+        span=int(rows.max()) if rows.size else 1,
     )
 
 
-def interpret_default() -> bool:
-    """Pallas interpret mode unless a real TPU backs the default device
-    (same auto-detection as the other kernel packages).  Benchmarks
-    record this flag so CPU rows are never mistaken for device rows."""
-    return jax.default_backend() != "tpu"
+def _map_rows(fn, x: jax.Array) -> jax.Array:
+    """``fn`` over every 1-D row ``x[..., :]`` as a sequential ``lax.map``:
+    only the stacked output is materialized.
+
+    On TPU this matters at graph scale: ``jnp.take(x, idx, axis=-1)``
+    lowers to a slice-per-index gather whose ``[n_idx, *lead]`` result
+    XLA then transposes, and one flat gather needs an index as large as
+    its output — each a second full-size buffer at the paper's widths."""
+    lead = x.shape[:-1]
+    out = jax.lax.map(fn, x.reshape((-1, x.shape[-1])))
+    return out.reshape(lead + out.shape[1:])
 
 
 def fused_lane_superstep(graph, csr: LaneCSR, state: DKSState,
@@ -142,7 +168,7 @@ def fused_lane_superstep(graph, csr: LaneCSR, state: DKSState,
     keeps its counters).
     """
     if interpret is None:
-        interpret = interpret_default()
+        interpret = interpret_mode()
     S0 = state.S                                    # [L, V, F, K]
     lanes = S0.shape[0]
     f, k = cfg.n_sets, cfg.k
@@ -152,26 +178,37 @@ def fused_lane_superstep(graph, csr: LaneCSR, state: DKSState,
     n_deep = jnp.sum(
         jnp.where(state.changed & ~state.first_fire, deg, 0.0), axis=1)
 
-    # Candidate gather (XLA): cand[l, row, slot] = S0[l, src] + w, masked
-    # by the sender's active flag — identical candidate multiset to the
-    # jnp relax (invalid edges carry w=INF and bump to INF either way).
-    src_flat = csr.src_pad.reshape(-1)              # [Vv*dmax]
-    fire = jnp.take(state.changed, src_flat, axis=1)
-    cand = (jnp.take(S0, src_flat, axis=1)
-            + csr.w_pad.reshape(-1)[None, :, None, None])
-    cand = jnp.where(fire[:, :, None, None], cand, INF)
-    cand = semiring.bump_to_inf(cand)
-    cand = cand.reshape(lanes, csr.n_rows, csr.dmax, f, k)
-    cand_t = cand.transpose(0, 3, 2, 4, 1).reshape(
-        lanes, f, csr.dmax * k, csr.n_rows)
+    # Candidate gather (XLA), built in the kernel's layout with the
+    # virtual-row axis minor: cand[l, s, kk, slot, row] = S0[l, src, s, kk]
+    # + w, masked by the sender's active flag — identical candidate
+    # multiset to the jnp relax (invalid edges carry w=INF and bump to INF
+    # either way).  The kernel min-reduces each row's dmax*K candidates,
+    # so their order is free.  Gathering rows last keeps the TPU's
+    # (8, 128) tiles full: a small minor axis (K, or dmax) would be
+    # padded to 128 lanes, many times the tensor's size.  One (lane,
+    # set, slot) row at a time, so the candidate tensor is the only
+    # full-size buffer.
+    S0_t = S0.transpose(0, 2, 3, 1)                 # [L, F, K, V]
+    src_t = csr.src_pad.T                           # [dmax, Vv]
+    w_t = csr.w_pad.T
 
-    s0_t = jnp.take(S0, csr.gather_of, axis=1).transpose(0, 2, 3, 1)
-    done_i = state.done.astype(jnp.int32).reshape(lanes, 1)
+    def lane_cand(args):
+        rows, changed = args                        # [F, K, V], [V]
+        fire = changed[src_t]                       # [dmax, Vv]
+        return _map_rows(lambda row: semiring.bump_to_inf(
+            jnp.where(fire, row[src_t] + w_t, INF)), rows)
+
+    cand_t = jax.lax.map(lane_cand, (S0_t, state.changed)).reshape(
+        lanes, f, k * csr.dmax, csr.n_rows)         # [L, F, K*dmax, Vv]
+
+    s0_t = _map_rows(lambda row: row[csr.gather_of], S0_t)  # [L, F, K, Vv]
+    done_i = state.done.astype(jnp.int32)
 
     out_t = fused_lane_step(cand_t, s0_t, csr.seg[None, :], done_i,
-                            m=cfg.m, block_v=csr.block_v,
+                            m=cfg.m, block_v=csr.block_v, span=csr.span,
                             interpret=interpret)   # [L, F, K, Vv]
-    S1 = jnp.take(out_t, csr.tail_row, axis=3).transpose(0, 3, 1, 2)
+    S1 = _map_rows(lambda row: row[csr.tail_row], out_t).transpose(
+        0, 3, 1, 2)
 
     nxt = dataclasses.replace(
         state,

@@ -16,6 +16,7 @@ import numpy as np
 
 from repro import INF
 from repro.core import semiring
+from repro.kernels import interpret_mode
 from repro.kernels.segment_minplus.kernel import padded_topk
 
 
@@ -74,7 +75,7 @@ def segment_minplus_padded(
 ) -> jax.Array:
     """One relax step: S[V, F, K] tables -> R[V, F, K] received tables."""
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_mode()
     v, f, _ = S.shape
     vv, dmax = csr.src_pad.shape
     # Gather source tables (+ edge length) — XLA gather, streams well.

@@ -40,16 +40,16 @@ def _topk_unique_rows(cand: jnp.ndarray, k: int) -> jnp.ndarray:
 
 def _combine_kernel(s_ref, o_ref, *, m: int, k: int):
     """s_ref/o_ref: [2^m, K, BV] block in VMEM."""
-    s = s_ref[...]
+    # Rows kept as a list of [K, BV] arrays and stacked once: Mosaic has
+    # no scatter, so ``s.at[t].set`` cannot lower.
+    rows = list(s_ref[...])
     for t, a, b in split_pairs(m):
-        av = s[a]                                      # [K, BV]
-        bv = s[b]
-        pair = av[:, None, :] + bv[None, :, :]         # [K, K, BV]
+        pair = rows[a][:, None, :] + rows[b][None, :, :]  # [K, K, BV]
         pair = jnp.minimum(pair, INF)
         cand = jnp.concatenate(
-            [s[t], pair.reshape(k * k, -1)], axis=0)   # [K+K^2, BV]
-        s = s.at[t].set(_topk_unique_rows(cand, k))
-    o_ref[...] = s
+            [rows[t], pair.reshape(k * k, -1)], axis=0)   # [K+K^2, BV]
+        rows[t] = _topk_unique_rows(cand, k)
+    o_ref[...] = jnp.stack(rows)
 
 
 @functools.partial(jax.jit, static_argnames=("m", "block_v", "interpret"))

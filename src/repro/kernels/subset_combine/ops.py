@@ -1,4 +1,5 @@
-"""jit wrapper: engine-layout in/out, TPU kernel or interpret fallback."""
+"""jit wrapper: engine-layout in/out; compiled on TPU, interpreted on CPU
+(:func:`repro.kernels.interpret_mode`)."""
 
 from __future__ import annotations
 
@@ -6,6 +7,7 @@ import jax
 import jax.numpy as jnp
 
 from repro import INF
+from repro.kernels import interpret_mode
 from repro.kernels.subset_combine.kernel import subset_combine_t
 
 
@@ -26,7 +28,7 @@ def subset_combine(S: jax.Array, m: int, n_passes_unused: int = 0,
     so ``n_passes_unused`` from the jnp path is ignored.
     """
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_mode()
     s_t = jnp.transpose(S, (1, 2, 0))          # [2^m, K, V]
     s_t, v = _pad_nodes(s_t, block_v)
     out = subset_combine_t(s_t, m, block_v=block_v, interpret=interpret)

@@ -29,6 +29,7 @@ from repro.configs import DKS_CONFIGS
 from repro.engine import ExecutionPolicy, QueryEngine, WeightPolicy
 from repro.graph.generators import lod_like_graph
 from repro.graph.index import InvertedIndex, mid_df_tokens
+from repro.launch import enable_compile_cache
 
 
 def add_weight_policy_args(ap: argparse.ArgumentParser) -> None:
@@ -58,12 +59,17 @@ def weight_policy_from_args(args) -> WeightPolicy:
                         predicates=preds)
 
 
-def load_dataset(name: str):
-    ds = DKS_CONFIGS[name]
+def generate_dataset(ds) -> tuple:
+    """A :class:`DKSBenchConfig` -> (graph, inverted index), generated
+    from its seed."""
     g, tokens = lod_like_graph(ds.n_nodes, ds.n_edges, seed=ds.seed,
                                vocab=ds.vocab, tau=ds.tau)
-    index = InvertedIndex.from_token_matrix(tokens)
-    return ds, g, index
+    return g, InvertedIndex.from_token_matrix(tokens)
+
+
+def load_dataset(name: str):
+    ds = DKS_CONFIGS[name]
+    return (ds, *generate_dataset(ds))
 
 
 def build_engine(name: str, policy: ExecutionPolicy | None = None,
@@ -133,6 +139,7 @@ def main() -> int:
         ap.error("--telemetry and --stream are mutually exclusive "
                  "(streaming is already per-superstep)")
 
+    enable_compile_cache()
     t0 = time.time()
     policy = ExecutionPolicy(
         backend=args.backend,

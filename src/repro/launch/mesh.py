@@ -3,7 +3,7 @@
 Defined as FUNCTIONS so importing this module never touches jax device
 state; the dry-run sets XLA_FLAGS for 512 host devices *before* any jax
 import and only then calls these.  Mesh construction goes through
-:mod:`repro.shardmap` so the same code runs on jax 0.4.x and >= 0.7.
+:mod:`repro.shardmap`.
 """
 
 from __future__ import annotations

@@ -49,6 +49,7 @@ import urllib.request
 import numpy as np
 
 from repro.engine import ExecutionPolicy
+from repro.launch import enable_compile_cache
 from repro.launch.dks_query import (add_weight_policy_args, build_engine,
                                     weight_policy_from_args)
 from repro.obs import MetricsServer, parse_prometheus
@@ -56,7 +57,7 @@ from repro.serve import DKSService, ServeConfig
 from repro.serve.loadgen import latency_split, make_trace, replay
 
 
-def verify_served(engine, trace, served, atol=1e-5):
+def verify_served(engine, trace, served, atol=1e-5, refs=None):
     """Check every served answer against the direct engine.
 
     Exact results must match the single-query weights; approximate
@@ -65,9 +66,11 @@ def verify_served(engine, trace, served, atol=1e-5):
     bound is the one asserted — ``opt_lower_bound`` follows the paper's
     reporting convention, whose SPA component is an estimator and may in
     principle overestimate.)  Returns (n_exact, n_approx); raises
-    AssertionError on any mismatch.
+    AssertionError on any mismatch.  ``refs``: a dict that receives the
+    direct results keyed ``(keywords, k)``, for callers that check more.
     """
-    refs: dict = {}
+    if refs is None:
+        refs = {}
     n_exact = n_approx = 0
     for req, srv in zip(trace, served):
         key = (req.keywords, req.k)
@@ -394,6 +397,7 @@ def main() -> int:
     if args.watch is not None and args.live is None:
         ap.error("--watch needs --live DIR")
 
+    enable_compile_cache()
     t0 = time.time()
     policy = ExecutionPolicy(
         backend=args.backend, partition=args.partition,

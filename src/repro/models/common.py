@@ -35,7 +35,7 @@ def constrain(x: jax.Array, *spec) -> jax.Array:
     current scope (e.g. "pod" inside the pipeline shard_map) are dropped
     from the spec (:func:`repro.shardmap.auto_axis_names`)."""
     am = shardmap.get_abstract_mesh()
-    if am is None or not shardmap.constraints_supported_here():
+    if am is None:
         return x
     axes = shardmap.auto_axis_names(am)
     if not axes:

@@ -1,39 +1,14 @@
-"""jax-version compatibility layer for the shard_map / mesh APIs.
+"""The one place this repo touches the shard_map / mesh APIs.
 
-The sharded DKS path (and every other ``shard_map`` user in this repo) was
-written against the jax >= 0.7 surface: ``jax.shard_map``,
+Thin helpers over JAX's native surface: ``jax.shard_map``,
 ``jax.make_mesh(..., axis_types=...)``, ``jax.set_mesh`` and
-``jax.sharding.get_abstract_mesh``.  Older jax (0.4.x, the pin in this
-container) spells the same machinery ``jax.experimental.shard_map.shard_map``
-with ``check_rep``/``auto`` keywords, a ``jax.sharding.Mesh`` that is itself
-the ambient-mesh context manager, and no axis types at all.
+``jax.sharding.get_abstract_mesh``.
 
-This module is the single place that difference lives.  Resolution rules:
-
-======================  ==============================  =======================
-helper                  jax >= 0.7 (native)             jax 0.4.x (fallback)
-======================  ==============================  =======================
-``shard_map``           ``jax.shard_map`` with          ``jax.experimental
-                        ``check_vma`` / ``axis_names``  .shard_map.shard_map``;
-                                                        ``check_vma`` becomes
-                                                        ``check_rep``,
-                                                        ``axis_names`` becomes
-                                                        the complementary
-                                                        ``auto`` frozenset
-``make_mesh``           ``jax.make_mesh`` with          ``jax.make_mesh``
-                        ``axis_types=(Auto, ...)``      without axis types
-``mesh_scope``          ``jax.set_mesh(mesh)``          the ``Mesh`` context
-                        (or ``jax.sharding.use_mesh``)  manager (``with mesh:``)
-``get_abstract_mesh``   ``jax.sharding                  the physical mesh the
-                        .get_abstract_mesh()``          enclosing ``mesh_scope``
-                                                        installed
-======================  ==============================  =======================
-
-``get_abstract_mesh`` normalizes "no mesh installed" to ``None`` on both
-generations (native jax returns an *empty* ``AbstractMesh`` instead), so
-callers write ``mesh = shardmap.get_abstract_mesh(); if mesh is None: ...``
-and never touch ``axis_names`` of an empty mesh.  Whatever it returns can be
-passed straight back to :func:`shard_map` as the ``mesh`` argument.
+``get_abstract_mesh`` normalizes "no mesh installed" to ``None`` (JAX
+returns an *empty* ``AbstractMesh`` instead), so callers write
+``mesh = shardmap.get_abstract_mesh(); if mesh is None: ...`` and never
+touch ``axis_names`` of an empty mesh.  Whatever it returns can be passed
+straight back to :func:`shard_map` as the ``mesh`` argument.
 
 Prefer *explicit* meshes over the ambient lookup wherever a mesh can be
 threaded through (e.g. ``FrontierGraph.mesh`` for the sharded DKS path);
@@ -44,14 +19,12 @@ carry one (sharding constraints deep inside a transformer block).
 from __future__ import annotations
 
 import contextlib
-import functools
 import threading
 from typing import Any, Callable, Iterable
 
 import jax
 
 __all__ = [
-    "HAS_NATIVE_SHARD_MAP",
     "shard_map",
     "make_mesh",
     "mesh_scope",
@@ -62,23 +35,18 @@ __all__ = [
     "constraints_supported_here",
 ]
 
-# jax >= 0.7 exposes shard_map/set_mesh at the top level; 0.4.x does not.
-HAS_NATIVE_SHARD_MAP: bool = hasattr(jax, "shard_map")
-
-# 0.4.x meshes carry no axis types, so inside a shard_map body there is no
-# way to ask jax which axes are Manual (constraining one is a lowering
-# error).  shard_map() below records its manual set in this thread-local
-# scope around the body instead; auto_axis_names() subtracts it.
+# Axes that code tracing a body through a manual-mode entry point other
+# than shard_map marks Manual (manual_axes_scope); auto_axis_names()
+# subtracts them.
 _tls = threading.local()
 
 
 @contextlib.contextmanager
 def manual_axes_scope(names: Iterable[str]):
-    """Mark ``names`` as Manual for :func:`auto_axis_names` in this thread.
-
-    Installed automatically by :func:`shard_map` around the body; exposed
-    for code that traces a body through some other manual-mode entry point.
-    """
+    """Mark ``names`` as Manual for :func:`auto_axis_names` in this
+    thread, for code that traces a body through a manual-mode entry point
+    other than :func:`shard_map` (whose Manual axes JAX records in the
+    mesh's axis types)."""
     prev = getattr(_tls, "manual_axes", frozenset())
     _tls.manual_axes = prev | frozenset(names)
     try:
@@ -96,43 +64,18 @@ def shard_map(
     check_vma: bool = True,
     axis_names: Iterable[str] | None = None,
 ) -> Callable:
-    """``jax.shard_map`` on any jax generation.
-
-    ``check_vma``: the jax >= 0.7 name for replication checking (0.4.x calls
-    it ``check_rep``).  ``axis_names``: the mesh axes the body is *manual*
-    over (all of them when None); on 0.4.x this is translated to the
-    complementary ``auto`` frozenset.
-    """
-    if HAS_NATIVE_SHARD_MAP:
-        kwargs: dict[str, Any] = dict(
-            mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_vma)
-        if axis_names is not None:
-            kwargs["axis_names"] = set(axis_names)
-        return jax.shard_map(f, **kwargs)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    # 0.4.x note: partial-manual (``auto`` nonempty) is broken in that
-    # XLA generation — a ppermute inside the body aborts the SPMD
-    # partitioner (``IsManualSubgroup`` check).  So the body always runs
-    # fully manual here; axes a native-jax caller would leave Auto simply
-    # replicate the body's computation (the in/out specs never mention
-    # them), which is numerically equivalent.
-    manual = frozenset(mesh.axis_names)
-
-    @functools.wraps(f)
-    def body(*args, **kw):
-        # Whenever jax traces the body, constrain()/auto_axis_names() must
-        # see these axes as Manual (native jax encodes that in axis_types).
-        with manual_axes_scope(manual):
-            return f(*args, **kw)
-
-    return _shard_map(
-        body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=bool(check_vma), auto=frozenset())
+    """``jax.shard_map``.  ``axis_names``: the mesh axes the body is
+    *manual* over (all of them when None)."""
+    kwargs: dict[str, Any] = dict(
+        mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=check_vma)
+    if axis_names is not None:
+        kwargs["axis_names"] = set(axis_names)
+    return jax.shard_map(f, **kwargs)
 
 
 def make_mesh(axis_shapes, axis_names, *, devices=None):
-    """A device mesh with Auto-typed axes on every jax generation.
+    """A device mesh with Auto-typed axes.
 
     Unlike bare ``jax.make_mesh``, the product of ``axis_shapes`` may be
     smaller than the local device count — the first ``prod(axis_shapes)``
@@ -145,80 +88,47 @@ def make_mesh(axis_shapes, axis_names, *, devices=None):
         local = jax.devices()
         if n < len(local):
             devices = local[:n]
-    try:
-        return jax.make_mesh(
-            tuple(axis_shapes), tuple(axis_names), devices=devices,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(tuple(axis_names)))
-    except (AttributeError, TypeError):  # pre-AxisType jax (<= 0.4.x)
-        return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
-                             devices=devices)
+    return jax.make_mesh(
+        tuple(axis_shapes), tuple(axis_names), devices=devices,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(tuple(axis_names)))
 
 
 def mesh_scope(mesh):
-    """Context manager installing ``mesh`` as the ambient mesh.
-
-    jax >= 0.7: ``jax.set_mesh`` (or ``jax.sharding.use_mesh``); 0.4.x: the
-    ``Mesh`` object itself is the context manager.  ``None`` is accepted and
-    yields a null context, so callers can write
-    ``with mesh_scope(self.mesh):`` unconditionally.
+    """Context manager installing ``mesh`` as the ambient mesh
+    (``jax.set_mesh``).  ``None`` is accepted and yields a null context,
+    so callers can write ``with mesh_scope(self.mesh):`` unconditionally.
     """
     if mesh is None:
         return contextlib.nullcontext()
-    set_mesh = getattr(jax, "set_mesh", None) or getattr(
-        jax.sharding, "use_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    return mesh  # jax 0.4.x: `with mesh:` installs the resource env
+    return jax.set_mesh(mesh)
 
 
 def get_abstract_mesh():
     """The ambient mesh installed by the enclosing :func:`mesh_scope`, or
     ``None`` when no mesh is active.
 
-    The returned object exposes ``.axis_names`` / ``.shape`` and is a valid
-    ``mesh=`` argument for :func:`shard_map` (an ``AbstractMesh`` on native
-    jax, the physical ``Mesh`` on 0.4.x).
+    The returned ``AbstractMesh`` exposes ``.axis_names`` / ``.shape`` and
+    is a valid ``mesh=`` argument for :func:`shard_map`.
     """
-    native = getattr(jax.sharding, "get_abstract_mesh", None)
-    if native is not None:
-        am = native()
-        if am is None or not am.axis_names:
-            return None
-        return am
-    from jax._src import mesh as _mesh_lib  # 0.4.x: Mesh ctx resource env
-    pm = _mesh_lib.thread_resources.env.physical_mesh
-    if pm is None or pm.empty:
+    am = jax.sharding.get_abstract_mesh()
+    if am is None or not am.axis_names:
         return None
-    return pm
+    return am
 
 
 def auto_axis_names(mesh) -> tuple[str, ...]:
-    """Mesh axes usable in sharding constraints (Auto-typed).
-
-    Native jax encodes this in ``mesh.axis_types`` (axes made Manual by an
-    enclosing shard_map are excluded).  0.4.x meshes carry no axis types
-    (``axis_types is None``); there the enclosing :func:`shard_map`'s
-    :func:`manual_axes_scope` supplies the Manual set.
-    """
+    """Mesh axes usable in sharding constraints: the Auto-typed ones
+    (``mesh.axis_types`` excludes axes an enclosing shard_map made
+    Manual), less any :func:`manual_axes_scope` marks."""
     manual = getattr(_tls, "manual_axes", frozenset())
-    types = getattr(mesh, "axis_types", None)
-    if types is None:
-        return tuple(n for n in mesh.axis_names if n not in manual)
-    return tuple(n for n, t in zip(mesh.axis_names, types)
+    return tuple(n for n, t in zip(mesh.axis_names, mesh.axis_types)
                  if "Auto" in str(t) and n not in manual)
 
 
 def constraints_supported_here() -> bool:
-    """Whether ``with_sharding_constraint`` is safe at this trace point.
-
-    Inside a 0.4.x shard_map body the partial-manual SPMD partitioner
-    crashes on sharding constraints (``IsManualSubgroup`` check), so
-    constraints — which are only performance hints — must be skipped
-    there.  Native jax handles them via axis types, where this is always
-    True.
-    """
-    return HAS_NATIVE_SHARD_MAP or not getattr(_tls, "manual_axes",
-                                               frozenset())
+    """Whether ``with_sharding_constraint`` is safe at this trace point:
+    always, since JAX tells Manual axes apart through axis types."""
+    return True
 
 
 def mesh_axis_size(mesh, *names: str) -> int:

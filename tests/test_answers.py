@@ -38,7 +38,7 @@ def lane_tables(g, masks_host, k, L=4, max_supersteps=24):
     kw = np.zeros((L, m, engine.device_graph.v_pad), bool)
     kw[:, :, : g.n_nodes] = masks_host
     fn = engine._executable(engine._config(m, k), "fused")
-    states = engine._execute(fn, engine.device_graph, jnp.asarray(kw))
+    states = engine._execute(fn, jnp.asarray(kw))
     return np.asarray(states.S), kw
 
 

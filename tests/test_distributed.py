@@ -2,8 +2,7 @@
 XLA_FLAGS=--xla_force_host_platform_device_count=8 (jax locks the device
 count at first init, so the main pytest process stays single-device).
 
-All mesh/shard_map plumbing goes through :mod:`repro.shardmap`, so these
-tests exercise whichever jax generation is installed (0.4.x or >= 0.7).
+All mesh/shard_map plumbing goes through :mod:`repro.shardmap`.
 """
 
 import json

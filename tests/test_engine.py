@@ -308,7 +308,7 @@ def test_engine_reexports_from_core():
 # Sharded partition in-process (1 local device -> 1-shard mesh).  The full
 # multi-device story lives in tests/test_distributed.py; these tier-1 tests
 # keep the shard_map code path and its engine plumbing exercised on every
-# pytest run, on any jax generation (via repro.shardmap).
+# pytest run.
 # ---------------------------------------------------------------------------
 
 

@@ -109,13 +109,76 @@ def test_lane_csr_builder_invariants():
 
 def test_lane_csr_hub_splitting_bumps_rows_not_dmax_past_block():
     """A hub with degree > dmax splits over multiple virtual rows; dmax
-    only auto-bumps when one node's rows would exceed a whole block."""
+    never changes (a hub too big for one block widens the block)."""
     dg = _device_graph(v=120, e=2000, seed=5)   # dense -> hubs
     csr = lane_csr_from_device_graph(dg, dmax=4)
     seg = np.asarray(csr.seg)
     counts = np.bincount(seg[seg >= 0])
     assert counts.max() > 1      # at least one split node
     assert counts.max() <= csr.block_v
+    assert csr.dmax == 4 and csr.span == counts.max()
+
+
+def _star(leaves, seed=0):
+    """Hub 0 joined to every leaf, plus a leaf chain; integer weights."""
+    from repro.graph.structure import build_graph
+    n = leaves + 1
+    src = np.concatenate([np.zeros(leaves, np.int32),
+                          np.arange(1, leaves, dtype=np.int32)])
+    dst = np.concatenate([np.arange(1, n, dtype=np.int32),
+                          np.arange(2, n, dtype=np.int32)])
+    w = np.random.default_rng(seed).integers(1, 9, src.size)
+    return build_graph(src, dst, n, w=w.astype(np.float32)).to_device()
+
+
+def test_fused_lane_superstep_on_widened_block():
+    """A 300-degree hub at dmax=1 widens the block to 512 rows and spans
+    300 of them: the hub-merge scan bounded by that span still merges
+    all of them, bit for bit with the vmapped jnp superstep."""
+    dg = _star(300)
+    csr = lane_csr_from_device_graph(dg, dmax=1)
+    assert (csr.dmax, csr.block_v, csr.span) == (1, 512, 300)
+    cfg_j = _DKSConfig(m=2, k=2, max_supersteps=8)
+    cfg_p = _DKSConfig(m=2, k=2, max_supersteps=8,
+                       relax_impl="pallas", combine_impl="pallas")
+    rng = np.random.default_rng(2)
+    masks = np.zeros((2, 2, dg.v_pad), bool)
+    for lane in range(2):
+        for kw in range(2):
+            masks[lane, kw, 1 + rng.choice(300, 40, replace=False)] = True
+    st = _lane_init(dg, jnp.asarray(masks), cfg_j)
+    ref = jax.vmap(lambda s: _superstep(dg, s, cfg_j))(st)
+    out = fused_lane_superstep(dg, csr, st, cfg_p)
+    np.testing.assert_array_equal(np.asarray(out.S), np.asarray(ref.S))
+    np.testing.assert_array_equal(np.asarray(out.topk_w),
+                                  np.asarray(ref.topk_w))
+
+
+def test_lane_csr_refuses_hub_past_widest_block():
+    """One block must hold a node's rows in VMEM, so a node with more
+    in-edges than ``dmax * MAX_BLOCK_V`` is refused, naming its degree."""
+    from repro.kernels.lane_superstep.ops import MAX_BLOCK_V
+    dg = _star(MAX_BLOCK_V + 1)
+    with pytest.raises(ValueError, match=f"{MAX_BLOCK_V + 1:,} in-edges"):
+        lane_csr_from_device_graph(dg, dmax=1)
+    csr = lane_csr_from_device_graph(dg, dmax=2)      # the widest block
+    assert (csr.block_v, csr.span) == (MAX_BLOCK_V, MAX_BLOCK_V // 2 + 1)
+
+
+def test_fused_lane_step_refuses_tile_past_vmem_budget():
+    """The kernel refuses, before lowering, a block whose candidate tile
+    exceeds its VMEM budget."""
+    from repro.kernels.lane_superstep.kernel import (MAX_CAND_TILE_BYTES,
+                                                     fused_lane_step)
+    m, k, dmax, bv = 4, 2, 16, 4096       # 2x MAX_CAND_TILE_BYTES
+    assert (1 << m) * dmax * k * bv * 4 == 2 * MAX_CAND_TILE_BYTES
+    args = (jax.ShapeDtypeStruct((1, 1 << m, dmax * k, bv), jnp.float32),
+            jax.ShapeDtypeStruct((1, 1 << m, k, bv), jnp.float32),
+            jax.ShapeDtypeStruct((1, bv), jnp.int32),
+            jax.ShapeDtypeStruct((1,), jnp.int32))
+    with pytest.raises(ValueError, match="candidate tile"):
+        jax.eval_shape(lambda *a: fused_lane_step(*a, m=m, block_v=bv),
+                       *args)
 
 
 def test_fused_lane_superstep_matches_vmapped_superstep():

@@ -149,3 +149,43 @@ def test_hlo_analyzer_dynamic_loop_flagged():
     c = jax.jit(f).lower(jnp.ones((8, 8))).compile()
     s = analyze_hlo(c.as_text())
     assert s.dynamic_loops >= 1
+
+
+tied = st.sampled_from([1.0, 2.0, 3.0, INF])
+
+
+@settings(max_examples=40, deadline=None)
+@given(b=st.integers(1, 3), n=st.integers(1, 6), m=st.integers(1, 3),
+       data=st.data())
+def test_smallest_k_2d_matches_flat_top_k(b, n, m, data):
+    """Same values and flat indices as ``lax.top_k`` of the negated,
+    flattened input: many ties (lower index first), all-INF rows, and k
+    up to N*M."""
+    import jax
+    k = data.draw(st.integers(1, n * m))
+    x = np.asarray(data.draw(st.lists(tied, min_size=b * n * m,
+                                      max_size=b * n * m)),
+                   np.float32).reshape(b, n, m)
+    x[0] = INF                                    # one all-INF table
+    got_v, got_i = semiring.smallest_k_2d(jnp.asarray(x), k)
+    neg, want_i = jax.lax.top_k(-jnp.asarray(x).reshape(b, n * m), k)
+    np.testing.assert_array_equal(np.asarray(got_v), -np.asarray(neg))
+    np.testing.assert_array_equal(np.asarray(got_i), np.asarray(want_i))
+
+
+@settings(max_examples=25, deadline=None)
+@given(n=st.integers(1, 30), v=st.integers(1, 8), f=st.integers(1, 3),
+       p=st.integers(1, 3), k=ks, seed=st.integers(0, 99))
+def test_segment_topk_pooled_matches_folded_rows(n, v, f, p, k, seed):
+    """``pooled=True`` reduces the last axis with the rows: the same
+    result as folding it into the row axis (tied and INF candidates
+    included)."""
+    rng = np.random.default_rng(seed)
+    vals_ = rng.choice(np.float32([1, 2, 3, 5, INF]), (n, f, p))
+    seg = rng.integers(0, v, n).astype(np.int32)
+    got = semiring.segment_topk_min(jnp.asarray(vals_), jnp.asarray(seg),
+                                    v, k, pooled=True)
+    folded = vals_.transpose(0, 2, 1).reshape(n * p, f)
+    want = semiring.segment_topk_min(jnp.asarray(folded),
+                                     jnp.asarray(np.repeat(seg, p)), v, k)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))

@@ -1,5 +1,4 @@
-"""repro.shardmap compat layer: the same calls must resolve and run on
-every jax generation (native >= 0.7 API or the 0.4.x experimental one).
+"""repro.shardmap helpers over JAX's native shard_map / mesh API.
 Single-device meshes here; multi-device behavior is covered by
 tests/test_distributed.py."""
 
@@ -41,13 +40,12 @@ def test_shard_map_executes_with_collective():
 
 
 def test_shard_map_axis_names_subset():
-    """axis_names={...} (partial-manual on native jax; fully-manual
-    fallback on 0.4.x) must trace and run."""
+    """axis_names={...} (a partial-manual body) must trace and run, with
+    sharding constraints allowed inside it."""
     mesh = shardmap.make_mesh((1,), ("data",))
 
     def block(x):
-        assert not shardmap.constraints_supported_here() or \
-            shardmap.HAS_NATIVE_SHARD_MAP
+        assert shardmap.constraints_supported_here()
         return x * 2.0
 
     f = jax.jit(shardmap.shard_map(
@@ -65,7 +63,7 @@ def test_auto_axis_names_respects_manual_scope():
 
 def test_mesh_scope_enables_sharding_constraint():
     """constrain()-style bare-PartitionSpec constraints must work under
-    mesh_scope on any jax generation (the models rely on this)."""
+    mesh_scope (the models rely on this)."""
     from repro.models.common import constrain
 
     mesh = shardmap.make_mesh((1,), ("data",))
