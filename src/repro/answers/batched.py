@@ -306,6 +306,7 @@ class BatchedBacktracer:
             return dict(node=node[:B], kind=kind[:B], child0=child0[:B],
                         child1=child1[:B], edge_u=edge_u[:B], fail=fail)
 
+        @jax.named_scope("dks.backtrace")
         def kernel(S_lanes, kw_lanes):
             # Candidate selection: value-ascending with ties at lower cell
             # index first, matching the host's stable argsort exactly.
@@ -350,6 +351,7 @@ class BatchedBacktracer:
         candidate_factor: int = 4,
         lanes: list[int] | None = None,
         n_nodes: int | None = None,
+        batch: BatchedBacktrace | None = None,
     ) -> list[tuple[list[AnswerTree], bool]]:
         """Device-batched :func:`collect_answers` for a whole bucket.
 
@@ -360,8 +362,12 @@ class BatchedBacktracer:
         collector either way.  ``lanes``: which lanes to collect (default
         all — serving passes the real lanes of a padded bucket).
         ``n_nodes``: real node count (kw mask columns beyond it are
-        padding)."""
-        batch = self.backtrace_lanes(S_lanes, kw_lanes, k, candidate_factor)
+        padding).  ``batch``: this bucket's :meth:`backtrace_lanes`
+        output when the caller already ran it (and timed it apart from
+        the host collection)."""
+        if batch is None:
+            batch = self.backtrace_lanes(S_lanes, kw_lanes, k,
+                                         candidate_factor)
         S_host = np.asarray(S_lanes)
         kw_host = np.asarray(kw_lanes)
         V = n_nodes if n_nodes is not None else self.graph.n_nodes

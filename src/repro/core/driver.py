@@ -62,7 +62,8 @@ def lane_view(state: DKSState, i: int) -> DKSState:
 
 def lane_init(graph: Any, kw_masks: jax.Array, cfg: DKSConfig) -> DKSState:
     """Superstep 0 for a batch of lanes.  ``kw_masks``: bool[L, m, V]."""
-    return jax.vmap(lambda m: init_state(graph, m, cfg))(kw_masks)
+    with jax.named_scope("dks.init"):
+        return jax.vmap(lambda m: init_state(graph, m, cfg))(kw_masks)
 
 
 # Per-lane freeze: lanes whose exit criterion fired keep their state and

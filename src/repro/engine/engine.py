@@ -61,6 +61,7 @@ from repro.core.dks import DKSConfig, DKSState, run_dks_instrumented
 from repro.core.driver import (lane_init, lane_superstep, lane_view,
                                run_lanes_telemetry)
 from repro.obs.telemetry import SuperstepTelemetry
+from repro.obs.trace import Trace, timed_span
 from repro.core.reconstruct import collect_answers
 from repro.core.spa import nu_lower_bound, spa_cover_dp, spa_ratio
 from repro.engine.policy import ExecutionPolicy
@@ -264,8 +265,10 @@ class QueryEngine:
         1 after any number of same-shape *and same-lane-count* queries =
         the cache works (a new lane count is a new input shape, so it
         re-traces once, like any jit)."""
-        kind = self._resolve_kind(kind)
-        key = (self._config(m, k, **overrides), self.policy.partition, kind)
+        return self._traces(self._config(m, k, **overrides), kind)
+
+    def _traces(self, cfg: DKSConfig, kind: str) -> int:
+        key = (cfg, self.policy.partition, self._resolve_kind(kind))
         return self._trace_counts.get(key, 0)
 
     @property
@@ -431,6 +434,7 @@ class QueryEngine:
         keep_state: bool = False,
         strict: bool = True,
         n_real: int | None = None,
+        trace: Trace | None = None,
         **overrides,
     ) -> list[QueryResult | None]:
         """Answer a batch of queries, amortizing graph residency and kernel
@@ -459,6 +463,14 @@ class QueryEngine:
         program resolves the top-candidate decompositions of every real
         lane at once, and only ragged stragglers re-run the host search —
         bit-identical results, batched cost.
+
+        ``trace``: a :class:`repro.obs.Trace` to record each bucket's
+        spans on — ``masks``, ``device_dispatch`` (``lanes``,
+        ``compiled``; the same two clock reads as ``wall_time_s``) and
+        ``extract`` with its children ``backtrace`` (the device program
+        and its readback), ``trees`` (host tree collection;
+        ``device_resolved``, ``host_fallbacks``) and ``results`` (per-lane
+        result construction).  None runs untraced.
         """
         n_real = len(queries) if n_real is None else n_real
         results: list[QueryResult | None] = [None] * len(queries)
@@ -467,34 +479,62 @@ class QueryEngine:
             buckets.setdefault(len(q), []).append(i)
         for m, idxs in sorted(buckets.items()):
             cfg = self._config(m, k, **overrides)
-            pairs = [self._masks(list(queries[i]), strict) for i in idxs]
-            masks = np.stack([p[0] for p in pairs])
-            t0 = time.perf_counter()
-            states, telemetry = self._run_fused(cfg, masks)
-            dt = time.perf_counter() - t0
-            pre: dict[int, tuple] = {}
-            if extract and self.batched_extraction:
-                topk = np.asarray(states.topk_w)
-                lanes = [bi for bi in range(len(idxs))
-                         if idxs[bi] < n_real and topk[bi, 0] < INF]
-                if lanes:
-                    S_lanes = states.S
-                    if self.mesh is not None:
-                        # Sharded runs leave S device-distributed; the
-                        # backtrace kernel is a plain single-device jit.
-                        S_lanes = np.asarray(S_lanes)
-                    pre = dict(zip(lanes, self._backtracer().extract_lanes(
-                        S_lanes, masks, k=max(cfg.k, extract_pool or 0),
-                        lanes=lanes, n_nodes=self.n_nodes)))
-            for bi, i in enumerate(idxs):
-                if i >= n_real:
-                    continue
-                results[i] = self._make_result(
-                    list(queries[i]), masks[bi], lane_view(states, bi), cfg,
-                    dt, extract, keep_state, unmatched=pairs[bi][1],
-                    extract_pool=extract_pool, answers_pre=pre.get(bi),
-                    telemetry=telemetry)
+            with timed_span(trace, "masks"):
+                pairs = [self._masks(list(queries[i]), strict) for i in idxs]
+                masks = np.stack([p[0] for p in pairs])
+            traces_before = self._traces(cfg, "fused")
+            with timed_span(trace, "device_dispatch",
+                            lanes=len(idxs)) as dispatch:
+                states, telemetry = self._run_fused(cfg, masks)
+                dispatch.set(
+                    compiled=self._traces(cfg, "fused") > traces_before)
+            dt = dispatch.t_end - dispatch.t_start
+            with timed_span(trace, "extract"):
+                pre: dict[int, tuple] = {}
+                if extract and self.batched_extraction:
+                    pre = self._extract_lanes(
+                        trace, states, masks, idxs, n_real,
+                        max(cfg.k, extract_pool or 0))
+                with timed_span(trace, "results"):
+                    for bi, i in enumerate(idxs):
+                        if i >= n_real:
+                            continue
+                        results[i] = self._make_result(
+                            list(queries[i]), masks[bi],
+                            lane_view(states, bi), cfg, dt, extract,
+                            keep_state, unmatched=pairs[bi][1],
+                            extract_pool=extract_pool,
+                            answers_pre=pre.get(bi), telemetry=telemetry)
         return results  # type: ignore[return-value]
+
+    def _extract_lanes(self, trace: Trace | None, states: DKSState,
+                       masks: np.ndarray, idxs: list[int], n_real: int,
+                       k: int) -> dict[int, tuple]:
+        """Answer trees of a bucket's real lanes with a finite answer, as
+        ``{lane: (ranked, exhausted)}``: the batched device backtrace
+        (``backtrace`` span), then the host collection (``trees``)."""
+        bt = self._backtracer()
+        with timed_span(trace, "backtrace"):
+            topk = np.asarray(states.topk_w)
+            lanes = [bi for bi in range(len(idxs))
+                     if idxs[bi] < n_real and topk[bi, 0] < INF]
+            S_lanes = states.S
+            if lanes and self.mesh is not None:
+                # Sharded runs leave S device-distributed; the backtrace
+                # kernel is a plain single-device jit.
+                S_lanes = np.asarray(S_lanes)
+            batch = (bt.backtrace_lanes(S_lanes, masks, k) if lanes
+                     else None)
+        before = bt.stats()
+        with timed_span(trace, "trees") as trees:
+            out = {}
+            if lanes:
+                out = dict(zip(lanes, bt.extract_lanes(
+                    S_lanes, masks, k=k, lanes=lanes, n_nodes=self.n_nodes,
+                    batch=batch)))
+            trees.set(**{name: n - before[name]
+                         for name, n in bt.stats().items()})
+        return out
 
     def query_stream(
         self,
@@ -627,6 +667,7 @@ class QueryEngine:
         keep_state: bool = False,
         strict: bool = True,
         n_real: int | None = None,
+        trace: Trace | None = None,
         **overrides,
     ) -> list[tuple[QueryResult, dict[str, Any]] | None]:
         """Serve a BUCKET of same-shape queries under one shared wall-clock
@@ -659,6 +700,12 @@ class QueryEngine:
         while the device steps the remaining lanes — by loop exit most
         trees already exist.  Interrupted lanes extract best-so-far trees
         from their frozen state at the deadline, alongside their bounds.
+
+        ``trace``: as in :meth:`query_batch` — ``masks``,
+        ``device_dispatch`` (the whole stepped loop; ``lanes``,
+        ``compiled``, ``driver_supersteps``) and ``extract`` with
+        ``trees`` (collecting the overlapped and inline extractions;
+        ``overlapped``, ``inline``) and ``results``.
         """
         queries = [list(q) for q in queries]
         if not queries:
@@ -670,80 +717,98 @@ class QueryEngine:
                 f"have the same keyword count (got m={sorted(ms)})")
         n_real = len(queries) if n_real is None else n_real
         cfg = self._config(ms.pop(), k, **overrides)
-        pairs = [self._masks(q, strict) for q in queries]
-        masks = np.stack([p[0] for p in pairs])
+        with timed_span(trace, "masks"):
+            pairs = [self._masks(q, strict) for q in queries]
+            masks = np.stack([p[0] for p in pairs])
         init_fn, step_fn = self._executable(cfg, "stepwise")
         overlap = None
         if extract:
             from repro.answers import ExtractionOverlap
             overlap = ExtractionOverlap(
                 self.graph, max(cfg.k, extract_pool or 0))
-        t0 = time.perf_counter()
-        deadline_t = t0 + max(deadline_s, 0.0)
-        state = self._execute(init_fn, jnp.asarray(masks))
-        own_t: list[float | None] = [None] * len(queries)
-        driver_steps = 0
-        while True:
-            done = np.asarray(state.done)
-            now = time.perf_counter()
-            for i in range(n_real):
-                if done[i] and own_t[i] is None:
-                    # The lane proved its exit here: that is ITS serve
-                    # time, even while the driver keeps stepping others.
-                    own_t[i] = now - t0
-                    if overlap is not None and \
-                            float(np.asarray(state.topk_w[i, 0])) < INF:
-                        # Frozen lane => final table: reconstruct its
-                        # trees NOW, under the remaining supersteps.
-                        overlap.submit(i, state.S[i],
-                                       masks[i][:, : self.n_nodes])
-            if done[:n_real].all() or now >= deadline_t:
-                break
-            state = self._execute(step_fn, state)
-            driver_steps += 1
-        dt = time.perf_counter() - t0
+        traces_before = self._traces(cfg, "stepwise")
+        with timed_span(trace, "device_dispatch",
+                        lanes=len(queries)) as dispatch:
+            t0 = dispatch.t_start
+            deadline_t = t0 + max(deadline_s, 0.0)
+            state = self._execute(init_fn, jnp.asarray(masks))
+            own_t: list[float | None] = [None] * len(queries)
+            driver_steps = 0
+            while True:
+                done = np.asarray(state.done)
+                now = time.perf_counter()
+                for i in range(n_real):
+                    if done[i] and own_t[i] is None:
+                        # The lane proved its exit here: that is ITS serve
+                        # time, even while the driver keeps stepping others.
+                        own_t[i] = now - t0
+                        if overlap is not None and \
+                                float(np.asarray(state.topk_w[i, 0])) < INF:
+                            # Frozen lane => final table: reconstruct its
+                            # trees NOW, under the remaining supersteps.
+                            overlap.submit(i, state.S[i],
+                                           masks[i][:, : self.n_nodes])
+                if done[:n_real].all() or now >= deadline_t:
+                    break
+                state = self._execute(step_fn, state)
+                driver_steps += 1
+            dispatch.set(
+                compiled=self._traces(cfg, "stepwise") > traces_before,
+                driver_supersteps=driver_steps)
+        dt = dispatch.t_end - dispatch.t_start
         out: list[tuple[QueryResult, dict[str, Any]] | None] = []
-        for i, q in enumerate(queries):
-            if i >= n_real:
-                out.append(None)
-                continue
-            lane = lane_view(state, i)
-            answers_pre = None
-            if overlap is not None and float(lane.topk_w[0]) < INF:
-                # Overlapped result for frozen lanes; inline best-so-far
-                # extraction for lanes the deadline interrupted.
-                answers_pre = overlap.result(
-                    i, lane.S, masks[i][:, : self.n_nodes]) \
-                    if not overlap.pending(i) else overlap.result(i)
-            interrupted = not bool(lane.done)
-            forced = bool(lane.budget_hit) or bool(lane.capped)
-            if interrupted or forced:
-                bounds = self._state_bounds(lane, cfg)
-                spa = bounds.spa
-                sound_lb = bounds.sound_lb
-                # Reported bound folds in the sound facts, so it can
-                # never sit below the guarantee it accompanies.
-                opt_lb = max(bounds.opt_lb, sound_lb)
-            else:
-                # Proven exit: the run certified its best answer — that
-                # IS the bound, and the O(3^m) cover DP is dead weight.
-                spa = None
-                opt_lb = sound_lb = min(float(lane.topk_w[0]), INF)
-            res = self._make_result(
-                q, masks[i], lane, cfg, dt, extract, keep_state,
-                unmatched=pairs[i][1],
-                own_time_s=own_t[i] if own_t[i] is not None else dt,
-                interrupted=interrupted, spa_hint=spa,
-                extract_pool=extract_pool, answers_pre=answers_pre)
-            info = dict(
-                opt_lower_bound=min(opt_lb, INF),
-                sound_opt_lower_bound=min(sound_lb, INF),
-                interrupted=interrupted,
-                driver_supersteps=driver_steps,
-            )
-            out.append((res, info))
+        with timed_span(trace, "extract"):
+            pre: dict[int, tuple] = {}
+            if overlap is not None:
+                with timed_span(trace, "trees") as trees:
+                    # Overlapped results for frozen lanes; inline
+                    # best-so-far extraction for lanes the deadline
+                    # interrupted.
+                    topk = np.asarray(state.topk_w)
+                    for i in range(n_real):
+                        if topk[i, 0] < INF:
+                            pre[i] = (overlap.result(i) if overlap.pending(i)
+                                      else overlap.result(
+                                          i, state.S[i],
+                                          masks[i][:, : self.n_nodes]))
+                    overlap.close()
+                    trees.set(**overlap.stats())
+            with timed_span(trace, "results"):
+                for i, q in enumerate(queries):
+                    if i >= n_real:
+                        out.append(None)
+                        continue
+                    lane = lane_view(state, i)
+                    interrupted = not bool(lane.done)
+                    forced = bool(lane.budget_hit) or bool(lane.capped)
+                    if interrupted or forced:
+                        bounds = self._state_bounds(lane, cfg)
+                        spa = bounds.spa
+                        sound_lb = bounds.sound_lb
+                        # Reported bound folds in the sound facts, so it
+                        # can never sit below the guarantee it
+                        # accompanies.
+                        opt_lb = max(bounds.opt_lb, sound_lb)
+                    else:
+                        # Proven exit: the run certified its best answer
+                        # — that IS the bound, and the O(3^m) cover DP is
+                        # dead weight.
+                        spa = None
+                        opt_lb = sound_lb = min(float(lane.topk_w[0]), INF)
+                    res = self._make_result(
+                        q, masks[i], lane, cfg, dt, extract, keep_state,
+                        unmatched=pairs[i][1],
+                        own_time_s=own_t[i] if own_t[i] is not None else dt,
+                        interrupted=interrupted, spa_hint=spa,
+                        extract_pool=extract_pool, answers_pre=pre.get(i))
+                    info = dict(
+                        opt_lower_bound=min(opt_lb, INF),
+                        sound_opt_lower_bound=min(sound_lb, INF),
+                        interrupted=interrupted,
+                        driver_supersteps=driver_steps,
+                    )
+                    out.append((res, info))
         if overlap is not None:
-            overlap.close()
             # Bucket-wide extraction split (how many tree reconstructions
             # hid behind device supersteps) — shared by every lane's info,
             # like driver_supersteps.

@@ -187,7 +187,9 @@ def fused_lane_superstep(graph, csr: LaneCSR, state: DKSState,
     # (8, 128) tiles full: a small minor axis (K, or dmax) would be
     # padded to 128 lanes, many times the tensor's size.  One (lane,
     # set, slot) row at a time, so the candidate tensor is the only
-    # full-size buffer.
+    # full-size buffer.  The named scopes (``dks.gather``, ``dks.kernel``,
+    # ``dks.finish``) label the ops in the compiled program and the
+    # profiler's device trace; they change no op.
     S0_t = S0.transpose(0, 2, 3, 1)                 # [L, F, K, V]
     src_t = csr.src_pad.T                           # [dmax, Vv]
     w_t = csr.w_pad.T
@@ -198,24 +200,27 @@ def fused_lane_superstep(graph, csr: LaneCSR, state: DKSState,
         return _map_rows(lambda row: semiring.bump_to_inf(
             jnp.where(fire, row[src_t] + w_t, INF)), rows)
 
-    cand_t = jax.lax.map(lane_cand, (S0_t, state.changed)).reshape(
-        lanes, f, k * csr.dmax, csr.n_rows)         # [L, F, K*dmax, Vv]
-
-    s0_t = _map_rows(lambda row: row[csr.gather_of], S0_t)  # [L, F, K, Vv]
+    with jax.named_scope("dks.gather"):
+        cand_t = jax.lax.map(lane_cand, (S0_t, state.changed)).reshape(
+            lanes, f, k * csr.dmax, csr.n_rows)     # [L, F, K*dmax, Vv]
+        s0_t = _map_rows(lambda row: row[csr.gather_of],
+                         S0_t)                      # [L, F, K, Vv]
     done_i = state.done.astype(jnp.int32)
 
-    out_t = fused_lane_step(cand_t, s0_t, csr.seg[None, :], done_i,
-                            m=cfg.m, block_v=csr.block_v, span=csr.span,
-                            interpret=interpret)   # [L, F, K, Vv]
-    S1 = _map_rows(lambda row: row[csr.tail_row], out_t).transpose(
-        0, 3, 1, 2)
-
-    nxt = dataclasses.replace(
-        state,
-        S=S1,
-        msgs_bfs=state.msgs_bfs + n_bfs,
-        msgs_deep=state.msgs_deep + n_deep,
-        step=state.step + 1,
-    )
-    return jax.vmap(
-        lambda s0, st: finish_superstep(graph, s0, st, cfg))(S0, nxt)
+    with jax.named_scope("dks.kernel"):
+        out_t = fused_lane_step(cand_t, s0_t, csr.seg[None, :], done_i,
+                                m=cfg.m, block_v=csr.block_v,
+                                span=csr.span,
+                                interpret=interpret)  # [L, F, K, Vv]
+    with jax.named_scope("dks.finish"):
+        S1 = _map_rows(lambda row: row[csr.tail_row], out_t).transpose(
+            0, 3, 1, 2)
+        nxt = dataclasses.replace(
+            state,
+            S=S1,
+            msgs_bfs=state.msgs_bfs + n_bfs,
+            msgs_deep=state.msgs_deep + n_deep,
+            step=state.step + 1,
+        )
+        return jax.vmap(
+            lambda s0, st: finish_superstep(graph, s0, st, cfg))(S0, nxt)

@@ -12,7 +12,7 @@ from .metrics import (Counter, DEFAULT_BUCKETS_MS, Gauge, Histogram,
                       MetricsRegistry, default_registry, parse_prometheus)
 from .telemetry import (HostTelemetryCollector, SuperstepTelemetry,
                         TELEMETRY_MAX_SUPERSTEPS)
-from .trace import Span, Trace, Tracer, render_span_tree
+from .trace import Span, Trace, Tracer, render_span_tree, timed_span
 
 __all__ = [
     "Counter",
@@ -31,4 +31,5 @@ __all__ = [
     "default_registry",
     "parse_prometheus",
     "render_span_tree",
+    "timed_span",
 ]

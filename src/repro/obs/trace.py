@@ -26,6 +26,12 @@ Design constraints (this sits on the serving hot path):
 Traces from requests that ride another request's work (micro-batch
 followers, single-flight attachees) carry a ``coalesced_into`` link to
 the leader's trace id instead of duplicating its spans.
+
+A :class:`Tracer` given an ``annotate`` factory (the service passes
+``jax.profiler.TraceAnnotation``; this package never imports jax) also
+enters ``annotate(f"dks.{name}")`` around every span opened as a context
+manager on a sampled trace, on the same thread, so a profiler capture
+shows the spans on the profiler's own clock beside the device's ops.
 """
 
 from __future__ import annotations
@@ -36,6 +42,7 @@ import threading
 import time
 import zlib
 from collections import deque
+from typing import Any, Callable
 
 
 class Span:
@@ -62,14 +69,21 @@ class Span:
 
 
 class _SpanHandle:
-    """Context manager returned by :meth:`Trace.span`; closes its span
-    (and pops it off the current thread's nesting stack) on exit."""
+    """Context manager returned by :meth:`Trace.span` and
+    :func:`timed_span`; closes its span (and pops it off the current
+    thread's nesting stack) on exit.  ``t_start``/``t_end`` are the
+    span's two clock reads — taken even when no span is recorded, so a
+    caller can time its work and record the span from the same reads."""
 
-    __slots__ = ("_trace", "_span")
+    __slots__ = ("_trace", "_span", "_note", "t_start", "t_end")
 
-    def __init__(self, trace: "Trace", span: Span | None) -> None:
+    def __init__(self, trace: "Trace | None", span: Span | None) -> None:
         self._trace = trace
         self._span = span
+        self._note = None
+        self.t_start = (span.t_start if span is not None
+                        else time.perf_counter())
+        self.t_end: float | None = None
 
     def set(self, **attrs) -> "_SpanHandle":
         if self._span is not None:
@@ -77,14 +91,30 @@ class _SpanHandle:
         return self
 
     def __enter__(self) -> "_SpanHandle":
+        if self._span is not None:
+            annotate = self._trace._tracer.annotate
+            if annotate is not None:
+                self._note = annotate(f"dks.{self._span.name}")
+                self._note.__enter__()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
+        if self._note is not None:
+            self._note.__exit__(exc_type, exc, tb)
+        self.t_end = time.perf_counter()
         if self._span is None:
             return
         if exc is not None:
             self._span.attrs.setdefault("error", repr(exc))
-        self._trace._close(self._span)
+        self._trace._close(self._span, self.t_end)
+
+
+def timed_span(trace: "Trace | None", name: str, **attrs) -> _SpanHandle:
+    """``trace.span(name, **attrs)``, or, with no trace, a handle that
+    only times: either way ``t_start``/``t_end`` hold the clock reads."""
+    if trace is None:
+        return _SpanHandle(None, None)
+    return trace.span(name, **attrs)
 
 
 class Trace:
@@ -130,8 +160,8 @@ class Trace:
         stack.append(sp.span_id)
         return _SpanHandle(self, sp)
 
-    def _close(self, sp: Span) -> None:
-        sp.t_end = time.perf_counter()
+    def _close(self, sp: Span, t_end: float) -> None:
+        sp.t_end = t_end
         stack = getattr(self._tls, "stack", None)
         if stack and stack[-1] == sp.span_id:
             stack.pop()
@@ -208,10 +238,14 @@ class Tracer:
     deterministic hash of ``(seed, trace_id)`` — see module docstring).
     ``log_path``: append each finished *sampled* trace as one JSON line
     (the structured event log ``serve_dks --trace-sample`` exposes).
+    ``annotate``: a factory ``name -> context manager`` entered as
+    ``dks.<span name>`` around every context-manager span of a sampled
+    trace (module docstring); None records spans only.
     """
 
     def __init__(self, capacity: int = 256, sample: float = 1.0,
-                 seed: int = 0, log_path: str | None = None) -> None:
+                 seed: int = 0, log_path: str | None = None,
+                 annotate: Callable[[str], Any] | None = None) -> None:
         if capacity < 1:
             raise ValueError("capacity must be >= 1")
         if not 0.0 <= sample <= 1.0:
@@ -220,6 +254,7 @@ class Tracer:
         self.sample = float(sample)
         self.seed = int(seed)
         self.log_path = log_path
+        self.annotate = annotate
         self._ring: deque[Trace] = deque(maxlen=self.capacity)
         self._lock = threading.Lock()
         self._ids = itertools.count(1)

@@ -18,6 +18,7 @@ so queue wait cannot eat the budget it counts against.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import queue
 import threading
@@ -92,7 +93,8 @@ class MicroBatcher:
 
     def __init__(self, dispatch: Callable[[list[Request]], None], *,
                  max_batch: int = 8, max_wait_ms: float = 5.0,
-                 max_batch_for: Callable[[], int] | None = None) -> None:
+                 max_batch_for: Callable[[], int] | None = None,
+                 annotate: Callable[[str], Any] | None = None) -> None:
         if max_batch < 1:
             raise ValueError("max_batch must be >= 1")
         self._dispatch = dispatch
@@ -105,6 +107,11 @@ class MicroBatcher:
         # max_batch, the classic behavior.
         self._max_batch_for = max_batch_for
         self.max_wait_s = float(max_wait_ms) / 1e3
+        # Optional profiler annotation factory (the service passes
+        # jax.profiler.TraceAnnotation): the blocking wait for requests
+        # is entered as ``dks.batcher_wait``, so a profile tells a device
+        # gap spent waiting for load from one spent on host bookkeeping.
+        self._annotate = annotate
         self._queue: queue.Queue = queue.Queue()
         self._thread: threading.Thread | None = None
         self._stopping = False
@@ -113,8 +120,13 @@ class MicroBatcher:
         # stopping).  Counters are monotone; ``current_reason`` is valid
         # inside a dispatch call (same thread, set right before it) and
         # lets the service stamp the reason on the bucket's trace span.
+        # ``current_depth`` likewise holds the queue depth the bucket left
+        # behind: ``waiting`` real requests (in the other pending buckets,
+        # the rest of its own, and the admission queue) and
+        # ``waiting_buckets`` (other non-empty buckets).
         self.dispatch_counts = {"full": 0, "window": 0, "flush": 0}
         self.current_reason: str | None = None
+        self.current_depth: dict[str, int] | None = None
         # Makes submit's running-check + enqueue atomic against stop():
         # any request admitted under the lock is enqueued before _STOP,
         # so the dispatcher always sees (and flushes) it before exiting.
@@ -203,11 +215,16 @@ class MicroBatcher:
         stopping = False
         while True:
             timeout = self._next_timeout(pending)
-            try:
-                item = self._queue.get(
-                    timeout=timeout) if timeout != 0 else None
-            except queue.Empty:
-                item = None
+            item = None
+            if timeout != 0:
+                wait = (self._annotate("dks.batcher_wait")
+                        if self._annotate is not None
+                        else contextlib.nullcontext())
+                try:
+                    with wait:
+                        item = self._queue.get(timeout=timeout)
+                except queue.Empty:
+                    pass
             drained = [] if item is None else [item]
             while True:
                 try:
@@ -230,13 +247,14 @@ class MicroBatcher:
             for key in list(pending):
                 group = pending[key]
                 while len(group) >= fill:
-                    self._safe_dispatch(group[:fill], "full")
+                    self._safe_dispatch(group[:fill], "full", pending, key)
                     del group[:fill]
                 if group and (stopping or
                               now - group[0].t_submit
                               >= self._window_s(group[0])):
                     self._safe_dispatch(
-                        group, "flush" if stopping else "window")
+                        group, "flush" if stopping else "window", pending,
+                        key)
                     group = []
                 if group:
                     pending[key] = group
@@ -270,10 +288,18 @@ class MicroBatcher:
         remaining = nearest - now
         return max(remaining, 0.0) if remaining > 1e-4 else 0
 
-    def _safe_dispatch(self, group: list[Request],
-                       reason: str = "window") -> None:
+    def _safe_dispatch(self, group: list[Request], reason: str,
+                       pending: dict[tuple, list[Request]],
+                       key: tuple) -> None:
+        """Dispatch ``group``, drawn from ``pending[key]`` (still in it)."""
         self.dispatch_counts[reason] += 1
         self.current_reason = reason
+        self.current_depth = {
+            "waiting": (sum(len(g) for g in pending.values()) - len(group)
+                        + self._queue.qsize()),
+            "waiting_buckets": sum(1 for other, g in pending.items()
+                                   if other != key and g),
+        }
         try:
             self._dispatch(group)
         except BaseException as exc:  # noqa: BLE001 — must resolve futures
@@ -282,3 +308,4 @@ class MicroBatcher:
                     req.future.set_exception(exc)
         finally:
             self.current_reason = None
+            self.current_depth = None

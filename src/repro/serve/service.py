@@ -41,36 +41,35 @@ threads only touch the cache, the admission queue, and their futures.
 **Observability** (:mod:`repro.obs`): every admitted request gets a trace
 (``ServedResult.trace_id``) whose spans walk the request's actual path —
 admit (with the cache lookup), queue wait, bucket coalesce (shape / fill /
-dispatch reason / deadline budget), device dispatch (compile-vs-warm,
-detected via the engine's trace counter), extraction (device-resolved vs
-host-fallback split), render/paginate, cache store.  Micro-batch riders
-and single-flight followers get their own trace with a ``coalesced_into``
-link to the bucket leader.  ``svc.registry`` exposes every ``ServeStats``
+dispatch reason / deadline budget / the queue depth left behind), then the
+engine's own spans of the dispatch (keyword masks, device dispatch with
+compile-vs-warm, extraction split into backtrace, trees and results),
+render/paginate, cache store.  Micro-batch riders and single-flight
+followers get their own trace with a ``coalesced_into`` link to the
+bucket leader.  Spans are also profiler annotations (``dks.<span>``) on
+the device trace's clock.  ``svc.registry`` exposes every ``ServeStats``
 counter (derived from the same snapshot at scrape time, so ``/metrics``
-can never drift from ``stats()``), engine executor counters, and
-latency/queue/device histograms in Prometheus text format —
+can never drift from ``stats()``), engine executor counters, and the
+request latency histogram in Prometheus text format —
 ``serve_dks --metrics-port`` serves it over HTTP.
 """
 
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import threading
 import time
 from concurrent.futures import CancelledError, Future
 from typing import Hashable, Sequence
 
+import jax
+
 from repro.answers import TreePage, diversified_order, paginate
 from repro.engine import AdaptiveLanePolicy, QueryEngine, QueryResult
-from repro.obs import MetricsRegistry, Tracer
+from repro.obs import MetricsRegistry, Tracer, timed_span
 from repro.serve.batcher import MicroBatcher, Request
 from repro.serve.cache import ResultCache
 from repro.serve.stats import ServeStats, StatsCollector
-
-# Stand-in context manager for unsampled/traceless span sites (entering
-# it any number of times is safe — nullcontext keeps no state).
-_NULL_SPAN = contextlib.nullcontext()
 
 
 @dataclasses.dataclass(frozen=True)
@@ -274,7 +273,8 @@ class DKSService:
             max_wait_ms=self.config.max_wait_ms,
             max_batch_for=(self.lane_policy.target_fill
                            if self.config.pad_batches == "adaptive"
-                           else None))
+                           else None),
+            annotate=jax.profiler.TraceAnnotation)
         # Cross-request single-flight: cache_token -> follower list of an
         # identical request currently in flight.  A second identical miss
         # attaches here instead of executing again; the leader's done
@@ -290,12 +290,15 @@ class DKSService:
         # Observability: one trace per admitted request (the span trees
         # behind ``--explain`` and ``/traces``) and a metrics registry
         # whose serving counters are DERIVED from ``self.stats()`` at
-        # scrape time — /metrics equals ServeStats by construction.
+        # scrape time — /metrics equals ServeStats by construction.  Span
+        # sites also enter a profiler annotation (``dks.<span>``), so a
+        # jax.profiler capture shows them on the device trace's clock.
         self.tracer = Tracer(
             capacity=self.config.trace_capacity,
             sample=self.config.trace_sample,
             seed=self.config.trace_seed,
-            log_path=self.config.trace_log)
+            log_path=self.config.trace_log,
+            annotate=jax.profiler.TraceAnnotation)
         self.registry = MetricsRegistry()
         self._wire_metrics()
 
@@ -309,20 +312,12 @@ class DKSService:
         Counters and gauges are scrape-time collectors over the SAME
         snapshots ``stats()`` / ``engine.*`` / ``tracer.stats()`` serve,
         so ``/metrics`` cannot drift from the Python-side reports.  Only
-        the latency histograms are direct instruments (a percentile
+        the latency histogram is a direct instrument (a percentile
         cannot be reconstructed at scrape time)."""
         reg = self.registry
         self._h_latency = reg.histogram(
             "dks_request_latency_ms",
             "End-to-end request latency (submit -> resolved future), ms.")
-        self._h_queue = reg.histogram(
-            "dks_queue_wait_ms",
-            "Admission-queue wait before bucket dispatch, ms "
-            "(dispatched requests only).")
-        self._h_device = reg.histogram(
-            "dks_device_time_ms",
-            "Compiled superstep program wall time billed to each "
-            "dispatched request, ms.")
 
         _C, _G = "counter", "gauge"
         serve_kinds = {
@@ -951,7 +946,8 @@ class DKSService:
         if leader is not None:
             attrs = dict(shape=f"m{len(group[0].keywords)}k{group[0].k}",
                          fill=len(group), lanes=n_lanes,
-                         reason=self._batcher.current_reason)
+                         reason=self._batcher.current_reason,
+                         **(self._batcher.current_depth or {}))
             if deadline_budget_ms is not None:
                 attrs["deadline_budget_ms"] = round(deadline_budget_ms, 3)
             leader.add_span("coalesce", group[0].t_submit, t_dispatch,
@@ -970,45 +966,23 @@ class DKSService:
         queries += [queries[-1]] * (self._padded_len(n_real) - n_real)
         t_dispatch = time.perf_counter()
         self._observe_dispatch(group, len(queries), t_dispatch)
-        leader = group[0].trace
         # Tree requests widen extraction to a ranked pool for the WHOLE
         # bucket (extraction is per-lane host work; the pool rides the
         # same device-batched backtrace pass either way) and force
         # extraction on even for weight-only configs.
         want_trees = any(req.return_trees for req in group)
         pool_n = group[0].k * cfg.tree_pool_factor if want_trees else None
-        # Compile-vs-warm split: the engine's trace counter moves exactly
-        # when this dispatch compiled a new executable for the shape.
         overrides = dict(group[0].overrides)
         m, k = len(group[0].keywords), group[0].k
-        traces_before = engine.trace_count(m, k, **overrides)
-        extract_before = engine.extraction_stats
         # n_real: padding lanes ride the device program for shape reuse
-        # but skip host-side result construction in the engine.
+        # but skip host-side result construction in the engine.  The
+        # engine records the dispatch's spans (compile-vs-warm among
+        # them) on the leader's trace.
         results = engine.query_batch(
             queries, k=k, extract=cfg.extract or want_trees,
             extract_pool=pool_n, strict=cfg.strict,
-            n_real=n_real, **overrides)
-        t_done = time.perf_counter()
-        compiled = engine.trace_count(m, k, **overrides) > traces_before
-        extract_after = engine.extraction_stats
-        # The engine's wall_time_s times the superstep loop alone; the
-        # rest of the dispatch interval is host-side extraction + result
-        # construction.  Splitting the interval at that boundary gives
-        # every rider an honest device span without a second clock read
-        # inside the engine.
+            n_real=n_real, trace=group[0].trace, **overrides)
         device_ms = results[0].wall_time_s * 1e3 if results else 0.0
-        t_device_end = min(t_done, t_dispatch + device_ms / 1e3)
-        if leader is not None:
-            leader.add_span("device_dispatch", t_dispatch, t_device_end,
-                            compiled=compiled, lanes=len(queries))
-            leader.add_span(
-                "extract", t_device_end, t_done,
-                mode="device" if cfg.extract or want_trees else "skipped",
-                device_resolved=(extract_after["device_resolved"]
-                                 - extract_before["device_resolved"]),
-                host_fallbacks=(extract_after["host_fallbacks"]
-                                - extract_before["host_fallbacks"]))
         self.lane_policy.observe(len(queries), device_ms)
         self._stats.record_dispatch(n_real, deadline=False,
                                     shape=(m, k, len(queries)))
@@ -1018,8 +992,7 @@ class DKSService:
         cacheable = engine is self.engine
         for req, res in zip(group, results):
             if cacheable:
-                with (req.trace.span("cache_store") if req.trace is not None
-                      else _NULL_SPAN):
+                with timed_span(req.trace, "cache_store"):
                     self._cache.put(req.cache_key, res)
                     if want_trees and res.answer_pool is not None:
                         self._tree_cache.put(
@@ -1028,9 +1001,9 @@ class DKSService:
             trees = None
             if req.return_trees:
                 self._stats.record_tree_request(cache_hit=False)
-                with (req.trace.span("render", ranking=req.tree_ranking,
-                                     cursor=req.tree_cursor)
-                      if req.trace is not None else _NULL_SPAN):
+                with timed_span(req.trace, "render",
+                                ranking=req.tree_ranking,
+                                cursor=req.tree_cursor):
                     trees = self._render_page(
                         (res.answer_pool or [], res.pool_exhausted), engine,
                         ranking=req.tree_ranking, cursor=req.tree_cursor,
@@ -1041,12 +1014,10 @@ class DKSService:
                                        queue_wait_ms=queue_ms,
                                        device_ms=device_ms)
             self._h_latency.observe((t_res - req.t_submit) * 1e3)
-            self._h_queue.observe(queue_ms)
-            self._h_device.observe(device_ms)
             trace_id = None
             if req.trace is not None:
                 trace_id = req.trace.trace_id
-                req.trace.set(outcome="served", compiled=compiled)
+                req.trace.set(outcome="served")
                 req.trace.finish()
             req.future.set_result(ServedResult(
                 result=res, cache_hit=False, approximate=False,
@@ -1074,35 +1045,19 @@ class DKSService:
         self._observe_dispatch(
             group, len(queries), t_dispatch,
             deadline_budget_ms=(deadline_t - t_dispatch) * 1e3)
-        leader = group[0].trace
         want_trees = any(req.return_trees for req in group)
         pool_n = group[0].k * cfg.tree_pool_factor if want_trees else None
         overrides = dict(group[0].overrides)
         m, k = len(group[0].keywords), group[0].k
-        traces_before = engine.trace_count(m, k, kind="stepwise",
-                                           **overrides)
         out = engine.query_deadline_batch(
             queries, k=k, extract=cfg.extract or want_trees,
             extract_pool=pool_n, strict=cfg.strict,
             deadline_s=deadline_t - time.perf_counter(), n_real=n_real,
-            **overrides)
+            trace=group[0].trace, **overrides)
         t_done = time.perf_counter()
-        compiled = engine.trace_count(m, k, kind="stepwise",
-                                      **overrides) > traces_before
         driver_steps = out[0][1]["driver_supersteps"] if out else 0
         lane_steps = sum(res.supersteps for res, _ in out[:n_real])
         device_ms = out[0][0].wall_time_s * 1e3 if out else 0.0
-        t_device_end = min(t_done, t_dispatch + device_ms / 1e3)
-        if leader is not None:
-            leader.add_span("device_dispatch", t_dispatch, t_device_end,
-                            compiled=compiled, lanes=len(queries),
-                            driver_supersteps=driver_steps)
-            extraction = (out[0][1].get("extraction", {})
-                          if out else {})
-            leader.add_span(
-                "extract", t_device_end, t_done,
-                mode="overlapped" if extraction else "inline",
-                **extraction)
         self.lane_policy.observe(len(queries), device_ms)
         self._stats.record_dispatch(n_real, deadline=True,
                                     driver_steps=driver_steps,
@@ -1117,8 +1072,7 @@ class DKSService:
                 # flight — the old-version key would be unreachable).
                 # Best-so-far results are budget-specific — never cached,
                 # and neither are their tree pools.
-                with (req.trace.span("cache_store") if req.trace is not None
-                      else _NULL_SPAN):
+                with timed_span(req.trace, "cache_store"):
                     self._cache.put(req.cache_key, res)
                     if want_trees and res.answer_pool is not None:
                         self._tree_cache.put(
@@ -1130,9 +1084,9 @@ class DKSService:
                 # For interrupted lanes these are the BEST-SO-FAR trees,
                 # served alongside their lower bound — the paper's
                 # early-termination answer, now with explanations.
-                with (req.trace.span("render", ranking=req.tree_ranking,
-                                     cursor=req.tree_cursor)
-                      if req.trace is not None else _NULL_SPAN):
+                with timed_span(req.trace, "render",
+                                ranking=req.tree_ranking,
+                                cursor=req.tree_cursor):
                     trees = self._render_page(
                         (res.answer_pool or [], res.pool_exhausted), engine,
                         ranking=req.tree_ranking, cursor=req.tree_cursor,
@@ -1143,13 +1097,10 @@ class DKSService:
                                        queue_wait_ms=queue_ms,
                                        device_ms=device_ms)
             self._h_latency.observe((t_done - req.t_submit) * 1e3)
-            self._h_queue.observe(queue_ms)
-            self._h_device.observe(device_ms)
             trace_id = None
             if req.trace is not None:
                 trace_id = req.trace.trace_id
-                req.trace.set(outcome="served", approximate=approximate,
-                              compiled=compiled)
+                req.trace.set(outcome="served", approximate=approximate)
                 req.trace.finish()
             req.future.set_result(ServedResult(
                 result=res, cache_hit=False, approximate=approximate,

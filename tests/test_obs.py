@@ -5,11 +5,17 @@ serve-layer /metrics surface (counters equal ServeStats, monotone across
 scrapes), and trace completeness under coalescing + single-flight."""
 
 import json
+import os
+import subprocess
+import sys
+import threading
 import urllib.request
+from pathlib import Path
 
 import numpy as np
 import pytest
 
+import repro
 from repro.engine import ExecutionPolicy, QueryEngine
 from repro.graph.generators import lod_like_graph
 from repro.graph.index import InvertedIndex
@@ -19,6 +25,7 @@ from repro.obs import (
     Tracer,
     parse_prometheus,
     render_span_tree,
+    timed_span,
 )
 from repro.serve import DKSService, ServeConfig
 from repro.serve.loadgen import latency_split
@@ -119,6 +126,143 @@ def test_sampling_deterministic_per_seed():
         h.set(x=1)
     tr.finish()
     assert tr.spans == [] and tracer.stats()["sampled"] == 0
+
+
+class _Annotations:
+    """A recording stand-in for ``jax.profiler.TraceAnnotation``."""
+
+    def __init__(self):
+        self.events = []
+
+    def __call__(self, name):
+        events = self.events
+
+        class _Note:
+            def __enter__(self):
+                events.append(("enter", name, threading.get_ident()))
+
+            def __exit__(self, *exc):
+                events.append(("exit", name, threading.get_ident()))
+
+        return _Note()
+
+
+def test_annotate_hook_wraps_context_spans_only():
+    notes = _Annotations()
+    tracer = Tracer(annotate=notes)
+    tr = tracer.begin("req")
+    with tr.span("outer"):
+        with tr.span("inner"):
+            pass
+    tr.add_span("retro", tr.t_start, tr.t_start + 0.001)
+    tr.finish()
+    me = threading.get_ident()
+    assert notes.events == [("enter", "dks.outer", me),
+                            ("enter", "dks.inner", me),
+                            ("exit", "dks.inner", me),
+                            ("exit", "dks.outer", me)]
+    # Unsampled traces open no annotation; a traceless handle only times.
+    notes.events.clear()
+    tr = Tracer(sample=0.0, annotate=notes).begin("req")
+    with tr.span("ignored"):
+        pass
+    with timed_span(None, "untraced") as h:
+        pass
+    assert notes.events == [] and tr.spans == []
+    assert h.t_end >= h.t_start
+
+
+def test_obs_imports_no_jax():
+    """The leaf package takes its profiler hook as an argument: importing
+    it loads no jax."""
+    code = ("import sys; import repro.obs; "
+            "assert 'jax' not in sys.modules, 'repro.obs imported jax'")
+    env = dict(os.environ, PYTHONPATH=os.pathsep.join(
+        [str(Path(repro.__file__).resolve().parents[1]),
+         os.environ.get("PYTHONPATH", "")]))
+    subprocess.run([sys.executable, "-c", code], env=env, check=True,
+                   timeout=120)
+
+
+def test_engine_spans_nest_and_tile_extract(engine):
+    toks = mid_df_tokens(engine.index, 6)
+    queries = [toks[0:2], toks[2:4], toks[4:6], toks[1:3]]
+    notes = _Annotations()
+    tracer = Tracer(annotate=notes)
+    tr = tracer.begin("req")
+    before = engine.trace_count(2, 2)
+    results = engine.query_batch(queries, k=2, trace=tr, n_real=3)
+    tr.finish()
+    spans = {sp.name: sp for sp in tr.spans}
+    assert len(tr.spans) == len(spans) == 6
+    dispatch, extract = spans["device_dispatch"], spans["extract"]
+    assert dispatch.attrs == {"lanes": 4, "compiled":
+                              engine.trace_count(2, 2) > before}
+    # The span and wall_time_s are the same two clock reads.
+    assert dispatch.t_end - dispatch.t_start == results[0].wall_time_s
+    assert spans["masks"].t_end <= dispatch.t_start
+    assert dispatch.t_end <= extract.t_start
+    children = [spans[n] for n in ("backtrace", "trees", "results")]
+    for sp in children:
+        assert sp.parent_id == extract.span_id
+    for a, b in zip(children, children[1:]):
+        assert a.t_end <= b.t_start
+    assert extract.t_start <= children[0].t_start
+    assert children[-1].t_end <= extract.t_end
+    covered = sum(sp.t_end - sp.t_start for sp in children)
+    assert covered >= 0.9 * (extract.t_end - extract.t_start)
+    trees = spans["trees"].attrs
+    assert trees["device_resolved"] + trees["host_fallbacks"] > 0
+    # Every context-manager span is also a profiler annotation.
+    entered = [name for kind, name, _ in notes.events if kind == "enter"]
+    assert entered == ["dks.masks", "dks.device_dispatch", "dks.extract",
+                       "dks.backtrace", "dks.trees", "dks.results"]
+
+
+def test_engine_untraced_batch_matches_traced_and_single(engine):
+    toks = mid_df_tokens(engine.index, 6)
+    queries = [toks[0:2], toks[2:4], toks[4:6]]
+    plain = engine.query_batch(queries, k=2)
+    traced = engine.query_batch(queries, k=2,
+                                trace=Tracer().begin("req"))
+    for q, a, b in zip(queries, plain, traced):
+        ref = engine.query(q, k=2)
+        for res in (a, b):
+            np.testing.assert_array_equal(res.weights, ref.weights)
+            assert [t.edges for t in res.answers] == \
+                [t.edges for t in ref.answers]
+            assert res.supersteps == ref.supersteps
+
+
+def test_engine_deadline_spans(engine):
+    toks = mid_df_tokens(engine.index, 4)
+    tracer = Tracer()
+    tr = tracer.begin("req")
+    out = engine.query_deadline_batch([toks[0:2], toks[2:4]], k=1,
+                                      deadline_s=60.0, trace=tr)
+    spans = {sp.name: sp for sp in tr.spans}
+    assert set(spans) == {"masks", "device_dispatch", "extract", "trees",
+                          "results"}
+    dispatch = spans["device_dispatch"]
+    assert dispatch.attrs["driver_supersteps"] == \
+        out[0][1]["driver_supersteps"]
+    assert dispatch.t_end - dispatch.t_start == out[0][0].wall_time_s
+    for name in ("trees", "results"):
+        assert spans[name].parent_id == spans["extract"].span_id
+    assert spans["trees"].attrs == out[0][1]["extraction"]
+
+
+def test_fused_program_carries_named_scopes():
+    g, tokens = lod_like_graph(200, 600, seed=5, vocab=40)
+    index = InvertedIndex.from_token_matrix(tokens)
+    eng = QueryEngine.build(g, index=index, policy=ExecutionPolicy(
+        backend="pallas", max_supersteps=4))
+    cfg = eng._config(2, 1)
+    masks = np.stack([eng._masks(mid_df_tokens(index, 2, hi=40))[0]])
+    hlo = eng._executable(cfg, "fused").lower(
+        eng.device_graph, eng.lane_csr, masks).compile().as_text()
+    for scope in ("dks.init", "dks.gather", "dks.kernel", "dks.finish"):
+        assert scope in hlo, scope
 
 
 def test_trace_log_jsonl(tmp_path):

@@ -3,6 +3,9 @@ result-cache hits that skip device execution (asserted via the engine's
 trace/executor counters), deadline-bounded approximate answers with valid
 SPA bounds, and multi-threaded client parity with direct engine.query."""
 
+import threading
+import time
+
 import numpy as np
 import pytest
 
@@ -74,6 +77,59 @@ def test_batcher_coalesces_same_shape_and_separates(engine):
     for q, srv in zip(m2 + m3, served):
         np.testing.assert_allclose(
             srv.result.weights, engine.query(q, k=1).weights)
+
+
+def wait_until(cond, timeout=120.0):
+    t_end = time.monotonic() + timeout
+    while not cond():
+        assert time.monotonic() < t_end, "timed out"
+        time.sleep(0.005)
+
+
+def test_coalesce_counts_requests_waiting_behind_a_held_dispatch(
+        engine, monkeypatch):
+    """Each bucket's ``coalesce`` span records the queue depth it left:
+    ``waiting`` real requests (other pending buckets and the admission
+    queue) and ``waiting_buckets`` (other non-empty buckets), read while
+    the dispatcher is held on a group."""
+    toks = mid_df_tokens(engine.index, 10)
+    gates = [threading.Event(), threading.Event()]
+    calls = []
+    query_batch = engine.query_batch
+
+    def held(*args, **kwargs):
+        calls.append(len(args[0]))
+        if len(calls) <= len(gates):
+            assert gates[len(calls) - 1].wait(120)
+        return query_batch(*args, **kwargs)
+
+    monkeypatch.setattr(engine, "query_batch", held)
+    with DKSService(engine, ServeConfig(max_batch=4, max_wait_ms=1.0,
+                                        cache_size=0)) as svc:
+        queue = svc._batcher._queue
+        first = svc.submit(toks[0:2], k=2)        # held on gate 0
+        wait_until(lambda: len(calls) == 1)
+        m2 = [svc.submit(q, k=1) for q in (toks[2:4], toks[4:6], toks[6:8])]
+        m3 = [svc.submit(q, k=1) for q in (toks[0:3], toks[3:6])]
+        wait_until(lambda: queue.qsize() == 5)
+        time.sleep(0.05)                          # both windows expire
+        gates[0].set()                            # m=2 bucket: gate 1
+        wait_until(lambda: len(calls) == 2)
+        late = svc.submit(toks[8:10], k=3)
+        wait_until(lambda: queue.qsize() == 1)
+        gates[1].set()
+        served = [f.result(timeout=300) for f in [first, *m2, *m3, late]]
+
+        def depth(result):
+            coalesce = next(sp for sp in svc.trace(result.trace_id).spans
+                            if sp.name == "coalesce")
+            return (coalesce.attrs["waiting"],
+                    coalesce.attrs["waiting_buckets"])
+
+        leaders = [served[0], served[1], served[4], served[6]]
+        assert [s.batch_size for s in leaders] == [1, 3, 2, 1]
+        assert [depth(s) for s in leaders] == [(0, 0), (2, 1), (1, 0),
+                                               (0, 0)]
 
 
 def test_cache_hit_skips_execution_and_normalizes(engine):
