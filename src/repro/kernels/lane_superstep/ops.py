@@ -43,6 +43,15 @@ from repro.kernels.lane_superstep.kernel import fused_lane_step
 # layout this wide at trace time.
 MAX_BLOCK_V = 4096
 
+# Largest padded temporary one chunk of the candidate row gather may take
+# in HBM (the gather's per-chunk counterpart of the kernel's
+# MAX_CAND_TILE_BYTES).  An f32[rows, 2^m*K] gather result pads its minor
+# axis to 128 lanes (up to 32x its size), and at the paper's widths the
+# 8-lane candidate tensor already takes a quarter of the chip, so the
+# gather runs in chunks sized against this.  At sec-rdfabout's 496,128
+# rows one m=3, K=2 slot takes 254 MB: one slot per chunk.
+MAX_GATHER_CHUNK_BYTES = 256 << 20
+
 
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass(frozen=True)
@@ -142,17 +151,19 @@ def lane_csr_from_device_graph(graph, dmax: int = 16,
     )
 
 
-def _map_rows(fn, x: jax.Array) -> jax.Array:
-    """``fn`` over every 1-D row ``x[..., :]`` as a sequential ``lax.map``:
-    only the stacked output is materialized.
-
-    On TPU this matters at graph scale: ``jnp.take(x, idx, axis=-1)``
-    lowers to a slice-per-index gather whose ``[n_idx, *lead]`` result
-    XLA then transposes, and one flat gather needs an index as large as
-    its output — each a second full-size buffer at the paper's widths."""
-    lead = x.shape[:-1]
-    out = jax.lax.map(fn, x.reshape((-1, x.shape[-1])))
-    return out.reshape(lead + out.shape[1:])
+def gather_chunks(dmax: int, n_rows: int, row_len: int) -> int:
+    """Slot groups per lane of the candidate row gather: the fewest (a
+    divisor of ``dmax``) whose ``[dmax / chunks * n_rows, row_len]`` f32
+    result, its minor axis padded to 128 lanes, stays within
+    :data:`MAX_GATHER_CHUNK_BYTES`.  ``row_len`` is a table row's
+    ``2^m * K``.  Never more than ``dmax`` chunks: one slot per chunk is
+    the finest split."""
+    slot_bytes = n_rows * -(-row_len // 128) * 128 * 4
+    for chunks in range(1, dmax + 1):
+        if dmax % chunks == 0 and \
+                dmax // chunks * slot_bytes <= MAX_GATHER_CHUNK_BYTES:
+            return chunks
+    return dmax
 
 
 def fused_lane_superstep(graph, csr: LaneCSR, state: DKSState,
@@ -178,33 +189,49 @@ def fused_lane_superstep(graph, csr: LaneCSR, state: DKSState,
     n_deep = jnp.sum(
         jnp.where(state.changed & ~state.first_fire, deg, 0.0), axis=1)
 
-    # Candidate gather (XLA), built in the kernel's layout with the
-    # virtual-row axis minor: cand[l, s, kk, slot, row] = S0[l, src, s, kk]
-    # + w, masked by the sender's active flag — identical candidate
-    # multiset to the jnp relax (invalid edges carry w=INF and bump to INF
-    # either way).  The kernel min-reduces each row's dmax*K candidates,
-    # so their order is free.  Gathering rows last keeps the TPU's
-    # (8, 128) tiles full: a small minor axis (K, or dmax) would be
-    # padded to 128 lanes, many times the tensor's size.  One (lane,
-    # set, slot) row at a time, so the candidate tensor is the only
-    # full-size buffer.  The named scopes (``dks.gather``, ``dks.kernel``,
-    # ``dks.finish``) label the ops in the compiled program and the
-    # profiler's device trace; they change no op.
-    S0_t = S0.transpose(0, 2, 3, 1)                 # [L, F, K, V]
-    src_t = csr.src_pad.T                           # [dmax, Vv]
-    w_t = csr.w_pad.T
+    # Candidate gather (XLA), one index per table row: a node's 2^m*K
+    # floats are contiguous in S's [L, V, 2^m, K] layout, so each
+    # (slot, row) takes its sender's whole row at once.  The sender's
+    # ``changed`` flag is folded into the rows first: INF + w bumps to INF
+    # for every non-negative w (pads carry w=INF), the value the jnp relax
+    # gives a silent sender, so the candidate multiset is identical.  The
+    # result is transposed into the kernel's layout, virtual rows minor
+    # (cand[l, s, (kk, slot), row]); the kernel min-reduces each row's
+    # dmax*K candidates, so their order is free.  The gather runs in
+    # chunks of slots (``gather_chunks``) for each lane in turn, written
+    # in place into the candidate tensor, the only full-size buffer;
+    # ``s0_t`` and the tail's ``S1`` (row gathers too) go one lane at a
+    # time, each lane's padded [Vv, F*K] as large as one slot.  The
+    # named scopes (``dks.gather``, ``dks.kernel``, ``dks.finish``) label
+    # the ops in the compiled program; they change no op.
+    fk, vv = f * k, csr.n_rows
+    chunks = gather_chunks(csr.dmax, vv, fk)
+    g = csr.dmax // chunks
+    src_c = csr.src_pad.T.reshape(chunks, g * vv)   # slot-major indices
+    w_c = csr.w_pad.T.reshape(chunks, g, vv)
 
-    def lane_cand(args):
-        rows, changed = args                        # [F, K, V], [V]
-        fire = changed[src_t]                       # [dmax, Vv]
-        return _map_rows(lambda row: semiring.bump_to_inf(
-            jnp.where(fire, row[src_t] + w_t, INF)), rows)
+    def rows_of(table):                             # [V, F, K] -> [V, F*K]
+        return table.reshape(table.shape[0], fk)
+
+    def cand_chunk(i, cand):
+        lane, c = i // chunks, i % chunks
+        rows = jnp.where(state.changed[lane][:, None], rows_of(S0[lane]),
+                         INF)
+        got = jnp.take(rows, src_c[c], axis=0, mode="clip")
+        got = semiring.bump_to_inf(got.reshape(g, vv, fk) + w_c[c][..., None])
+        piece = got.reshape(g, vv, f, k).transpose(2, 3, 0, 1)
+        return jax.lax.dynamic_update_slice(
+            cand, piece.reshape(1, f, k * g, vv), (lane, 0, c * k * g, 0))
+
+    def lane_s0(table):                             # [V, F, K] -> [F, K, Vv]
+        got = jnp.take(rows_of(table), csr.gather_of, axis=0, mode="clip")
+        return got.reshape(vv, f, k).transpose(1, 2, 0)
 
     with jax.named_scope("dks.gather"):
-        cand_t = jax.lax.map(lane_cand, (S0_t, state.changed)).reshape(
-            lanes, f, k * csr.dmax, csr.n_rows)     # [L, F, K*dmax, Vv]
-        s0_t = _map_rows(lambda row: row[csr.gather_of],
-                         S0_t)                      # [L, F, K, Vv]
+        cand_t = jax.lax.fori_loop(
+            0, lanes * chunks, cand_chunk,
+            jnp.empty((lanes, f, k * csr.dmax, vv), S0.dtype))
+        s0_t = jax.lax.map(lane_s0, S0)             # [L, F, K, Vv]
     done_i = state.done.astype(jnp.int32)
 
     with jax.named_scope("dks.kernel"):
@@ -213,8 +240,10 @@ def fused_lane_superstep(graph, csr: LaneCSR, state: DKSState,
                                 span=csr.span,
                                 interpret=interpret)  # [L, F, K, Vv]
     with jax.named_scope("dks.finish"):
-        S1 = _map_rows(lambda row: row[csr.tail_row], out_t).transpose(
-            0, 3, 1, 2)
+        S1 = jax.lax.map(
+            lambda out: jnp.take(out.reshape(fk, vv).T, csr.tail_row,
+                                 axis=0, mode="clip").reshape(-1, f, k),
+            out_t)                                  # [L, V, F, K]
         nxt = dataclasses.replace(
             state,
             S=S1,

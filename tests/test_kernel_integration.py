@@ -229,3 +229,100 @@ def test_fused_lane_superstep_freezes_done_lane():
                                   np.asarray(st.S[0]))      # frozen
     assert not np.array_equal(np.asarray(out.S[1]),
                               np.asarray(st.S[1]))          # advanced
+
+
+def _parity_state(dg, m, cfg, lanes=4, seed=4):
+    """A lane-batched state two jnp supersteps in, with lane 1 done and
+    lane 2's ``changed`` all false (a lane whose senders all fall
+    silent)."""
+    import dataclasses as dc
+    from repro.core.driver import lane_superstep
+
+    rng = np.random.default_rng(seed)
+    masks = np.zeros((lanes, m, dg.v_pad), bool)
+    for lane in range(lanes):
+        for kw in range(m):
+            masks[lane, kw, rng.choice(dg.n_nodes, 3, replace=False)] = True
+    st = _lane_init(dg, jnp.asarray(masks), cfg)
+    for _ in range(2):
+        st = lane_superstep(dg, st, cfg)
+    return dc.replace(
+        st, done=st.done.at[1].set(True), changed=st.changed.at[2].set(False))
+
+
+@pytest.mark.parametrize("layout", ["hub_split", "widened_block"])
+@pytest.mark.parametrize("m,k", [(2, 1), (3, 5), (4, 2)])
+def test_fused_lane_superstep_parity(layout, m, k):
+    """The pallas lane superstep (row gather, kernel, tail) equals the
+    vmapped jnp superstep bit for bit, through the driver's freeze, on
+    4 lanes with one done and one whose senders are all silent; row
+    lengths 2^m*K of 4 (not a multiple of 8), 40 and 32."""
+    from repro.core.driver import lane_superstep
+
+    if layout == "hub_split":
+        dg = _device_graph()
+        csr = lane_csr_from_device_graph(dg, dmax=4)
+        assert csr.span > 1
+    else:
+        dg = _star(300)
+        csr = lane_csr_from_device_graph(dg, dmax=1)
+        assert csr.block_v > 128
+    cfg_j = _DKSConfig(m=m, k=k, max_supersteps=8)
+    cfg_p = _DKSConfig(m=m, k=k, max_supersteps=8,
+                       relax_impl="pallas", combine_impl="pallas")
+    st = _parity_state(dg, m, cfg_j)
+    ref = lane_superstep(dg, st, cfg_j)
+    out = lane_superstep(dg, st, cfg_p, csr)
+    for name in ("S", "changed", "topk_w", "done", "msgs_bfs",
+                 "msgs_deep"):
+        np.testing.assert_array_equal(np.asarray(getattr(out, name)),
+                                      np.asarray(getattr(ref, name)),
+                                      err_msg=name)
+    np.testing.assert_array_equal(np.asarray(out.S[1]), np.asarray(st.S[1]))
+
+
+@pytest.mark.parametrize("m,k", [(m, k) for m in (2, 3, 4)
+                                 for k in (1, 2, 5, 10)])
+def test_gather_chunks_one_at_cell_shapes(m, k):
+    """At the benchmark cell's layout (3,840 virtual rows of 16 slots)
+    every query shape gathers each lane in one chunk."""
+    from repro.kernels.lane_superstep import gather_chunks
+    assert gather_chunks(16, 3_840, (1 << m) * k) == 1
+
+
+def test_gather_chunks_split_at_paper_scale():
+    """At sec-rdfabout's 496,128 virtual rows, m=3 K=2, one slot's
+    padded rows (254 MB) fill the chunk budget: one slot per chunk."""
+    from repro.kernels.lane_superstep import gather_chunks
+    from repro.kernels.lane_superstep.ops import MAX_GATHER_CHUNK_BYTES
+    one_slot = 496_128 * 128 * 4
+    assert one_slot <= MAX_GATHER_CHUNK_BYTES < 2 * one_slot
+    assert gather_chunks(16, 496_128, 16) == 16
+    # Chunks always divide dmax, and a row past the budget alone still
+    # gathers one slot at a time.
+    assert gather_chunks(16, 3 * one_slot // (128 * 4), 16) == 16
+    assert gather_chunks(6, 100, 16) == 1
+
+
+@pytest.mark.parametrize("slots_per_chunk", [2, 1])
+def test_fused_lane_superstep_chunked_gather(monkeypatch, slots_per_chunk):
+    """A gather forced into several chunks per lane gives the one-chunk
+    result bit for bit."""
+    from repro.kernels.lane_superstep import gather_chunks, ops
+
+    m, k = 3, 2
+    dg = _device_graph()
+    csr = lane_csr_from_device_graph(dg, dmax=4)
+    cfg = _DKSConfig(m=m, k=k, max_supersteps=8,
+                     relax_impl="pallas", combine_impl="pallas")
+    st = _parity_state(dg, m, cfg)
+    assert gather_chunks(csr.dmax, csr.n_rows, 16) == 1
+    whole = fused_lane_superstep(dg, csr, st, cfg)
+    monkeypatch.setattr(ops, "MAX_GATHER_CHUNK_BYTES",
+                        slots_per_chunk * csr.n_rows * 128 * 4)
+    assert gather_chunks(csr.dmax, csr.n_rows, 16) == 4 // slots_per_chunk
+    split = fused_lane_superstep(dg, csr, st, cfg)
+    for name in ("S", "changed", "topk_w"):
+        np.testing.assert_array_equal(np.asarray(getattr(split, name)),
+                                      np.asarray(getattr(whole, name)),
+                                      err_msg=name)

@@ -265,6 +265,26 @@ def test_fused_program_carries_named_scopes():
         assert scope in hlo, scope
 
 
+def test_pallas_dispatch_names_its_gather_chunks():
+    """On the pallas path both dispatch spans carry the candidate
+    gather's chunk count, which the program's static shapes fix."""
+    from repro.kernels.lane_superstep import gather_chunks
+
+    g, tokens = lod_like_graph(200, 600, seed=5, vocab=40)
+    index = InvertedIndex.from_token_matrix(tokens)
+    eng = QueryEngine.build(g, index=index, policy=ExecutionPolicy(
+        backend="pallas", max_supersteps=4))
+    toks = mid_df_tokens(index, 2, hi=40)
+    csr = eng.lane_csr
+    want = gather_chunks(csr.dmax, csr.n_rows, 4)
+    tr = Tracer().begin("req")
+    eng.query_batch([toks], k=1, trace=tr)
+    eng.query_deadline_batch([toks], k=1, deadline_s=60.0, trace=tr)
+    got = [sp.attrs["gather_chunks"] for sp in tr.spans
+           if sp.name == "device_dispatch"]
+    assert got == [want, want] == [1, 1]
+
+
 def test_trace_log_jsonl(tmp_path):
     log = tmp_path / "traces.jsonl"
     tracer = Tracer(capacity=8, log_path=str(log))

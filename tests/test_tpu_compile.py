@@ -88,3 +88,45 @@ def test_subset_combine_compiles_for_v5e(one_chip):
     s_t = _spec((1 << M, K, NODES), jnp.float32, one_chip)
     compiled = subset_combine_t.lower(s_t, m=M).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("lanes", [1, 8])
+def test_fused_lane_superstep_fits_v5e(one_chip, lanes):
+    """The whole pallas superstep (the chunked row gather, the kernel and
+    the jnp tail) at sec-rdfabout's widths compiles for a v5e, and its
+    temporaries leave room on the chip's 16 GB: the paper-scale memory
+    the gather's chunking guards."""
+    from repro.core.driver import lane_init
+    from repro.engine import ExecutionPolicy
+    from repro.graph.structure import DeviceGraph
+    from repro.kernels.lane_superstep.ops import (LaneCSR,
+                                                  fused_lane_superstep,
+                                                  gather_chunks)
+
+    n_nodes, n_edges = 460_451, 990_648       # symmetric edges, unpadded
+    graph = DeviceGraph(
+        src=_spec((n_edges,), jnp.int32, one_chip),
+        dst=_spec((n_edges,), jnp.int32, one_chip),
+        w=_spec((n_edges,), jnp.float32, one_chip),
+        valid=_spec((n_edges,), jnp.bool_, one_chip),
+        out_degree=_spec((n_nodes,), jnp.int32, one_chip),
+        node_valid=_spec((n_nodes,), jnp.bool_, one_chip),
+        n_nodes=n_nodes, n_edges=n_edges)
+    csr = LaneCSR(
+        src_pad=_spec((ROWS, DMAX), jnp.int32, one_chip),
+        w_pad=_spec((ROWS, DMAX), jnp.float32, one_chip),
+        gather_of=_spec((ROWS,), jnp.int32, one_chip),
+        seg=_spec((ROWS,), jnp.int32, one_chip),
+        tail_row=_spec((n_nodes,), jnp.int32, one_chip),
+        dmax=DMAX, block_v=BLOCK_V, n_rows=ROWS, span=-(-4_445 // DMAX))
+    cfg = ExecutionPolicy(backend="pallas").dks_config(M, K)
+    state = jax.tree.map(
+        lambda x: _spec(x.shape, x.dtype, one_chip),
+        jax.eval_shape(lambda g, m: lane_init(g, m, cfg), graph,
+                       jax.ShapeDtypeStruct((lanes, M, n_nodes), jnp.bool_)))
+    assert gather_chunks(DMAX, ROWS, (1 << M) * K) > 1
+    compiled = jax.jit(
+        lambda g, c, s: fused_lane_superstep(g, c, s, cfg, interpret=False)
+    ).lower(graph, csr, state).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 12e9
