@@ -15,8 +15,11 @@ This module provides:
   answer is a newly-appearing full-set value, so BFS may stop once
   ``nu[full] >= W_K``.
 
-All DPs are over the 2^m keyword-set lattice (m <= ~6), so they are
-unrolled statically and cost nothing next to the graph-sized work.
+All DPs are over the 2^m keyword-set lattice (m <= ~6) and cost nothing
+next to the graph-sized work.  ``nu_lower_bound`` runs inside every
+compiled superstep, so it is one vectorized step per popcount level
+(:func:`pair_levels`), not one per split pair (301 at m = 6);
+``spa_cover_dp`` runs on the host, on forced exits only.
 """
 
 from __future__ import annotations
@@ -25,6 +28,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro import INF
 
@@ -67,12 +71,27 @@ def nu_lower_bound(
     """
     nu = jnp.minimum(g + e_min, INF)
     nu = nu.at[0].set(INF)
-    for t, a, b in split_pairs(m):
+    # A pair reads sets of lower popcount only, so each popcount level is
+    # one vectorized min over its pairs: the program grows with m, not
+    # with the 3^m pairs.
+    for t_ids, a_ids, b_ids in pair_levels(m):
         cand = jnp.minimum(
-            jnp.minimum(nu[a] + g[b], g[a] + nu[b]), nu[a] + nu[b]
-        )
-        nu = nu.at[t].min(jnp.minimum(cand, INF))
+            jnp.minimum(nu[a_ids] + g[b_ids], g[a_ids] + nu[b_ids]),
+            nu[a_ids] + nu[b_ids])
+        nu = nu.at[t_ids].min(jnp.minimum(cand, INF))
     return nu
+
+
+@functools.lru_cache(maxsize=None)
+def pair_levels(m: int) -> tuple[tuple[np.ndarray, np.ndarray, np.ndarray],
+                                 ...]:
+    """:func:`split_pairs` grouped by the popcount of ``t``: one
+    ``(t, a, b)`` triple of index arrays per level, levels ascending."""
+    levels: dict[int, list] = {}
+    for t, a, b in split_pairs(m):
+        levels.setdefault(bin(t).count("1"), []).append((t, a, b))
+    return tuple(tuple(np.asarray(col, np.int32) for col in zip(*levels[p]))
+                 for p in sorted(levels))
 
 
 def spa_cover_dp(shat: jax.Array, m: int) -> jax.Array:

@@ -466,8 +466,8 @@ class QueryEngine:
 
         ``trace``: a :class:`repro.obs.Trace` to record each bucket's
         spans on — ``masks``, ``device_dispatch`` (``lanes``,
-        ``compiled``, ``gather_chunks`` on the pallas path; the same two
-        clock reads as ``wall_time_s``) and
+        ``compiled``; ``gather_chunks`` and ``set_chunks`` on the pallas
+        path; the same two clock reads as ``wall_time_s``) and
         ``extract`` with its children ``backtrace`` (the device program
         and its readback), ``trees`` (host tree collection;
         ``device_resolved``, ``host_fallbacks``) and ``results`` (per-lane
@@ -485,7 +485,7 @@ class QueryEngine:
                 masks = np.stack([p[0] for p in pairs])
             traces_before = self._traces(cfg, "fused")
             with timed_span(trace, "device_dispatch", lanes=len(idxs),
-                            **self._gather_plan(cfg)) as dispatch:
+                            **self._chunk_plan(cfg)) as dispatch:
                 states, telemetry = self._run_fused(cfg, masks)
                 dispatch.set(
                     compiled=self._traces(cfg, "fused") > traces_before)
@@ -704,7 +704,8 @@ class QueryEngine:
 
         ``trace``: as in :meth:`query_batch` — ``masks``,
         ``device_dispatch`` (the whole stepped loop; ``lanes``,
-        ``compiled``, ``driver_supersteps``, ``gather_chunks``) and
+        ``compiled``, ``driver_supersteps``, ``gather_chunks``,
+        ``set_chunks``) and
         ``extract`` with ``trees`` (collecting the overlapped and inline
         extractions; ``overlapped``, ``inline``) and ``results``.
         """
@@ -729,7 +730,7 @@ class QueryEngine:
                 self.graph, max(cfg.k, extract_pool or 0))
         traces_before = self._traces(cfg, "stepwise")
         with timed_span(trace, "device_dispatch", lanes=len(queries),
-                        **self._gather_plan(cfg)) as dispatch:
+                        **self._chunk_plan(cfg)) as dispatch:
             t0 = dispatch.t_start
             deadline_t = t0 + max(deadline_s, 0.0)
             state = self._execute(init_fn, jnp.asarray(masks))
@@ -951,16 +952,19 @@ class QueryEngine:
             return jax.block_until_ready(
                 fn(self.device_graph, self.lane_csr, x))
 
-    def _gather_plan(self, cfg: DKSConfig) -> dict[str, int]:
-        """``{"gather_chunks": n}``: the slot groups per lane of the fused
-        pallas superstep's candidate gather for this shape (static per
-        program); empty off that path."""
+    def _chunk_plan(self, cfg: DKSConfig) -> dict[str, int]:
+        """``{"gather_chunks": n, "set_chunks": c}``: the slot groups per
+        lane of the fused pallas superstep's candidate gather, and the
+        keyword-set chunks per row block of its kernel, for this shape
+        (static per program); empty off that path."""
         csr = self.lane_csr
         if csr is None or cfg.relax_impl != "pallas":
             return {}
-        from repro.kernels.lane_superstep import gather_chunks
+        from repro.kernels.lane_superstep import gather_chunks, set_chunks
         return {"gather_chunks": gather_chunks(csr.dmax, csr.n_rows,
-                                               cfg.n_sets * cfg.k)}
+                                               cfg.n_sets * cfg.k),
+                "set_chunks": set_chunks(cfg.n_sets, csr.dmax * cfg.k,
+                                         csr.block_v)}
 
     def _run_fused(self, cfg: DKSConfig, masks: np.ndarray):
         """One fused-driver dispatch over lane-batched masks.  Returns

@@ -4,3 +4,4 @@ from repro.kernels.lane_superstep.ops import (  # noqa: F401
     gather_chunks,
     lane_csr_from_device_graph,
 )
+from repro.kernels.lane_superstep.kernel import set_chunks  # noqa: F401

@@ -155,3 +155,24 @@ def test_explored_fraction_less_than_full():
     state, _, _ = run_engine(g, groups, exit_mode="sound", max_supersteps=64)
     explored = float(jnp.mean(state.visited[: g.n_nodes]))
     assert explored < 0.9
+
+
+@pytest.mark.parametrize("m", [2, 3, 4, 5, 6])
+def test_nu_lower_bound_levels_equal_pair_sweep(m):
+    """The exit bound, one vectorized min per popcount level, equals the
+    pair-by-pair sweep in popcount order bit for bit, unseen sets
+    included."""
+    from repro.core.spa import nu_lower_bound, split_pairs
+
+    rng = np.random.default_rng(m)
+    for _ in range(5):
+        g = rng.integers(0, 40, 1 << m).astype(np.float32) / 4
+        g[rng.random(1 << m) < 0.3] = INF
+        e_min = np.float32(rng.integers(1, 4) / 2)
+        want = jnp.minimum(jnp.asarray(g) + e_min, INF).at[0].set(INF)
+        for t, a, b in split_pairs(m):
+            cand = jnp.minimum(jnp.minimum(want[a] + g[b], g[a] + want[b]),
+                               want[a] + want[b])
+            want = want.at[t].min(jnp.minimum(cand, INF))
+        got = nu_lower_bound(jnp.asarray(g), jnp.float32(e_min), m)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))

@@ -166,12 +166,13 @@ def test_lane_csr_refuses_hub_past_widest_block():
 
 
 def test_fused_lane_step_refuses_tile_past_vmem_budget():
-    """The kernel refuses, before lowering, a block whose candidate tile
-    exceeds its VMEM budget."""
+    """The kernel refuses, before lowering, a block where one keyword
+    set's candidate tile alone exceeds its VMEM budget (a wider tile of
+    several sets splits into set chunks instead)."""
     from repro.kernels.lane_superstep.kernel import (MAX_CAND_TILE_BYTES,
                                                      fused_lane_step)
-    m, k, dmax, bv = 4, 2, 16, 4096       # 2x MAX_CAND_TILE_BYTES
-    assert (1 << m) * dmax * k * bv * 4 == 2 * MAX_CAND_TILE_BYTES
+    m, k, dmax, bv = 4, 20, 16, 4096      # one set: 1.25x the budget
+    assert dmax * k * bv * 4 == 5 * MAX_CAND_TILE_BYTES // 4
     args = (jax.ShapeDtypeStruct((1, 1 << m, dmax * k, bv), jnp.float32),
             jax.ShapeDtypeStruct((1, 1 << m, k, bv), jnp.float32),
             jax.ShapeDtypeStruct((1, bv), jnp.int32),
@@ -326,3 +327,151 @@ def test_fused_lane_superstep_chunked_gather(monkeypatch, slots_per_chunk):
         np.testing.assert_array_equal(np.asarray(getattr(split, name)),
                                       np.asarray(getattr(whole, name)),
                                       err_msg=name)
+
+
+@pytest.mark.parametrize("m,k", [(5, 10), (6, 10)])
+def test_fused_lane_superstep_wide_parity(m, k):
+    """At m=5 and m=6, K=10 (90 and 301 split pairs; the 16-slot layout's
+    m=6 tile in two set chunks), the pallas lane superstep equals the
+    vmapped jnp superstep bit for bit, through the driver's freeze, on
+    4 lanes with one done and one whose senders are all silent."""
+    from repro.core.driver import lane_superstep
+    from repro.kernels.lane_superstep import set_chunks
+
+    dg = _device_graph(v=100, e=300)
+    csr = lane_csr_from_device_graph(dg)
+    assert set_chunks(1 << m, csr.dmax * k, csr.block_v) == m - 4
+    cfg_j = _DKSConfig(m=m, k=k, max_supersteps=8)
+    cfg_p = _DKSConfig(m=m, k=k, max_supersteps=8,
+                       relax_impl="pallas", combine_impl="pallas")
+    st = _parity_state(dg, m, cfg_j)
+    ref = lane_superstep(dg, st, cfg_j)
+    out = lane_superstep(dg, st, cfg_p, csr)
+    for name in ("S", "changed", "topk_w", "done", "msgs_bfs",
+                 "msgs_deep"):
+        np.testing.assert_array_equal(np.asarray(getattr(out, name)),
+                                      np.asarray(getattr(ref, name)),
+                                      err_msg=name)
+    np.testing.assert_array_equal(np.asarray(out.S[1]), np.asarray(st.S[1]))
+
+
+@pytest.mark.parametrize("chunks", [2, 4])
+def test_fused_lane_superstep_set_chunks(monkeypatch, chunks):
+    """A kernel forced to reduce its keyword sets in 2 or 4 chunks per
+    row block gives the one-chunk result bit for bit."""
+    from repro.kernels.lane_superstep import kernel, set_chunks
+
+    m, k = 3, 2
+    dg = _device_graph()
+    csr = lane_csr_from_device_graph(dg, dmax=4)
+    cfg = _DKSConfig(m=m, k=k, max_supersteps=8,
+                     relax_impl="pallas", combine_impl="pallas")
+    st = _parity_state(dg, m, cfg)
+    one_set = csr.dmax * k * csr.block_v * 4
+    assert set_chunks(8, csr.dmax * k, csr.block_v) == 1
+    whole = fused_lane_superstep(dg, csr, st, cfg)
+    monkeypatch.setattr(kernel, "MAX_CAND_TILE_BYTES", 8 // chunks * one_set)
+    kernel.fused_lane_step.clear_cache()        # re-plan at trace time
+    try:
+        assert set_chunks(8, csr.dmax * k, csr.block_v) == chunks
+        split = fused_lane_superstep(dg, csr, st, cfg)
+    finally:
+        kernel.fused_lane_step.clear_cache()
+    for name in ("S", "changed", "topk_w"):
+        np.testing.assert_array_equal(np.asarray(getattr(split, name)),
+                                      np.asarray(getattr(whole, name)),
+                                      err_msg=name)
+
+
+@pytest.mark.parametrize("m,k", [(m, k) for m in (2, 3, 4)
+                                 for k in (1, 2, 5, 10)])
+def test_set_chunks_one_at_lod_exact_shapes(m, k):
+    """At the cells' layout (128-row blocks of 16 slots) every shape of
+    ``lod-exact`` keeps its whole candidate tile in one set chunk."""
+    from repro.kernels.lane_superstep import set_chunks
+    assert set_chunks(1 << m, 16 * k, 128) == 1
+
+
+def test_set_chunks_split_wide_shapes_and_hub_blocks():
+    """m=6 K=10 at 128-row blocks (a 5,242,880 B tile) splits in two;
+    a hub-widened block splits its sets instead of refusing the shape;
+    only a block where one set's tile alone is over the budget is
+    refused."""
+    from repro.kernels.lane_superstep import set_chunks
+    from repro.kernels.lane_superstep.kernel import MAX_CAND_TILE_BYTES
+    from repro.kernels.lane_superstep.ops import MAX_BLOCK_V
+
+    assert (1 << 6) * 160 * 128 * 4 == 5_242_880 > MAX_CAND_TILE_BYTES
+    assert set_chunks(1 << 6, 160, 128) == 2
+    assert set_chunks(1 << 5, 160, 128) == 1
+    assert set_chunks(1 << 6, 32, 128) == 1
+    # Hub-widened blocks: m=4 K=2 at 4,096 rows (an 8 MiB tile) and m=4
+    # K=10 at the widest block (one set 2.6 MB).
+    assert set_chunks(1 << 4, 32, 4096) == 2
+    assert set_chunks(1 << 4, 160, MAX_BLOCK_V) == 16
+    with pytest.raises(ValueError, match="candidate tile"):
+        set_chunks(1 << 4, 320, MAX_BLOCK_V)
+
+
+def _bench_reference():
+    import importlib.util
+    import sys
+    from pathlib import Path
+
+    path = Path(__file__).resolve().parents[1] / "bench" / "reference.py"
+    spec = importlib.util.spec_from_file_location("bench_reference", path)
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[spec.name] = mod            # for its dataclasses
+    spec.loader.exec_module(mod)
+    return mod
+
+
+@pytest.mark.parametrize("m", [5, 6])
+def test_engine_wide_queries_match_references(m):
+    """m=5 and m=6, K=10, through ``QueryEngine`` on the pallas path (its
+    default 16-slot layout: two set chunks at m=6): the answer weights
+    equal the jnp engine's bit for bit and the plain reference's
+    (``bench/reference.py``); the best equals the Dreyfus-Wagner
+    optimum; every answer tree's weight is one of the brute-force
+    enumeration's minimal-tree weights, the first its least."""
+    from repro.core.steiner_ref import brute_force_topk, dreyfus_wagner
+    from repro.engine import ExecutionPolicy, QueryEngine
+    from repro.graph.index import InvertedIndex
+    from repro.kernels.lane_superstep import set_chunks
+
+    reference = _bench_reference()
+    k, n = 10, 10
+    g = random_weighted_graph(n, 13, seed=m, max_w=4)
+    rng = np.random.default_rng(m)
+    tokens = np.full((n, m), -1, np.int64)
+    for i in range(m):
+        for v in rng.choice(n, 1 + i % 2, replace=False):
+            tokens[v, int(np.argmax(tokens[v] < 0))] = i
+    index = InvertedIndex.from_token_matrix(tokens)
+    groups = [index.lookup(i) for i in range(m)]
+    eng = {b: QueryEngine.build(g, index=index,
+                                policy=ExecutionPolicy(backend=b))
+           for b in ("pallas", "jnp")}
+    csr = eng["pallas"].lane_csr
+    assert set_chunks(1 << m, csr.dmax * k, csr.block_v) == m - 4
+    got = {b: e.query_batch([list(range(m))], k=k)[0]
+           for b, e in eng.items()}
+    np.testing.assert_array_equal(got["pallas"].weights, got["jnp"].weights)
+    assert [a.edges for a in got["pallas"].answers] == [
+        a.edges for a in got["jnp"].answers]
+
+    src, dst, w = [], [], []
+    for v in range(n):
+        nbrs, ws = g.neighbors(v)
+        src += [v] * len(nbrs)
+        dst += list(nbrs)
+        w += list(ws)
+    sym = reference.SymGraph.from_directed(n, np.asarray(src),
+                                           np.asarray(dst), np.asarray(w))
+    np.testing.assert_array_equal(
+        got["pallas"].weights, reference.answer_weights(sym, groups, k))
+    assert got["pallas"].weights[0] == dreyfus_wagner(g, groups) < INF
+    achievable = [x for x in brute_force_topk(g, groups, 1000) if x < INF]
+    trees = [a.weight for a in got["pallas"].answers]
+    assert trees and trees[0] == achievable[0]
+    assert all(any(abs(t - x) < 1e-3 for x in achievable) for t in trees)

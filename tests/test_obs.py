@@ -285,6 +285,23 @@ def test_pallas_dispatch_names_its_gather_chunks():
     assert got == [want, want] == [1, 1]
 
 
+def test_pallas_dispatch_names_its_set_chunks():
+    """The dispatch span carries the kernel's keyword-set chunks: one at
+    m=2 K=1, two at m=6 K=10 on the 16-slot, 128-row layout (a
+    5,242,880 B tile whole)."""
+    g, tokens = lod_like_graph(200, 600, seed=5, vocab=40)
+    index = InvertedIndex.from_token_matrix(tokens)
+    eng = QueryEngine.build(g, index=index, policy=ExecutionPolicy(
+        backend="pallas", max_supersteps=4))
+    assert (eng.lane_csr.dmax, eng.lane_csr.block_v) == (16, 128)
+    tr = Tracer().begin("req")
+    eng.query_batch([mid_df_tokens(index, 2, hi=40)], k=1, trace=tr)
+    eng.query_batch([mid_df_tokens(index, 6, hi=40)], k=10, trace=tr)
+    got = [(sp.attrs["gather_chunks"], sp.attrs["set_chunks"])
+           for sp in tr.spans if sp.name == "device_dispatch"]
+    assert got == [(1, 1), (1, 2)]
+
+
 def test_trace_log_jsonl(tmp_path):
     log = tmp_path / "traces.jsonl"
     tracer = Tracer(capacity=8, log_path=str(log))

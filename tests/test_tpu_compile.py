@@ -130,3 +130,63 @@ def test_fused_lane_superstep_fits_v5e(one_chip, lanes):
     ).lower(graph, csr, state).compile()
     assert "tpu_custom_call" in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < 12e9
+
+
+# The lod-m56-exact cell's graph (lod-sec-rdfabout at 1/128): 3,597
+# nodes, 7,460 symmetric edges, a LaneCSR of 3,840 rows of 16 slots in
+# 128-row blocks, at most 13 rows per node.
+CELL_NODES, CELL_EDGES, CELL_ROWS, CELL_SPAN = 3_597, 7_460, 3_840, 13
+
+
+def test_fused_lane_step_compiles_set_chunked_for_v5e(one_chip):
+    """m=6, K=10 at the cell's layout: its 5,242,880 B candidate tile
+    splits into two set chunks, and the chunked kernel compiles."""
+    from repro.kernels.lane_superstep import set_chunks
+
+    m, k, f = 6, 10, 1 << 6
+    assert set_chunks(f, DMAX * k, 128) == 2
+    args = (_spec((2, f, DMAX * k, CELL_ROWS), jnp.float32, one_chip),
+            _spec((2, f, k, CELL_ROWS), jnp.float32, one_chip),
+            _spec((1, CELL_ROWS), jnp.int32, one_chip),
+            _spec((2,), jnp.int32, one_chip))
+    compiled = fused_lane_step.lower(*args, m=m, block_v=128,
+                                     span=CELL_SPAN).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_fused_lane_superstep_m6_fits_v5e(one_chip):
+    """The whole pallas superstep at m=6, K=10, 8 lanes, at the cell's
+    sizes compiles for a v5e with temporaries under 12 GB."""
+    from repro.core.driver import lane_init
+    from repro.engine import ExecutionPolicy
+    from repro.graph.structure import DeviceGraph
+    from repro.kernels.lane_superstep.ops import (LaneCSR,
+                                                  fused_lane_superstep)
+
+    m, k, lanes = 6, 10, 8
+    graph = DeviceGraph(
+        src=_spec((CELL_EDGES,), jnp.int32, one_chip),
+        dst=_spec((CELL_EDGES,), jnp.int32, one_chip),
+        w=_spec((CELL_EDGES,), jnp.float32, one_chip),
+        valid=_spec((CELL_EDGES,), jnp.bool_, one_chip),
+        out_degree=_spec((CELL_NODES,), jnp.int32, one_chip),
+        node_valid=_spec((CELL_NODES,), jnp.bool_, one_chip),
+        n_nodes=CELL_NODES, n_edges=CELL_EDGES)
+    csr = LaneCSR(
+        src_pad=_spec((CELL_ROWS, DMAX), jnp.int32, one_chip),
+        w_pad=_spec((CELL_ROWS, DMAX), jnp.float32, one_chip),
+        gather_of=_spec((CELL_ROWS,), jnp.int32, one_chip),
+        seg=_spec((CELL_ROWS,), jnp.int32, one_chip),
+        tail_row=_spec((CELL_NODES,), jnp.int32, one_chip),
+        dmax=DMAX, block_v=128, n_rows=CELL_ROWS, span=CELL_SPAN)
+    cfg = ExecutionPolicy(backend="pallas").dks_config(m, k)
+    state = jax.tree.map(
+        lambda x: _spec(x.shape, x.dtype, one_chip),
+        jax.eval_shape(lambda g, ms: lane_init(g, ms, cfg), graph,
+                       jax.ShapeDtypeStruct((lanes, m, CELL_NODES),
+                                            jnp.bool_)))
+    compiled = jax.jit(
+        lambda g, c, s: fused_lane_superstep(g, c, s, cfg, interpret=False)
+    ).lower(graph, csr, state).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 12e9
